@@ -27,37 +27,30 @@ Sections:
 * kernels_*: Pallas interpret-mode kernels vs jnp oracles.
 * train_step_* / decode_step_*: smoke-size LM steps (end-to-end
   substrate sanity + µs tracking).
+
+This process only orchestrates: it never imports JAX, and runs every
+section in a child of its own (``benchmarks/sections.py`` for the
+in-process sections, the section's script for the others), so each
+child owns its devices.  A section whose child fails is reported as a
+``failed:`` row and makes the run exit non-zero.  Every child shares
+the persistent compilation cache of :mod:`repro.jax_cache`.
 """
+
 from __future__ import annotations
 
+import json
 import os
+import subprocess
 import sys
-import time
 
-# Make ``benchmarks.*`` importable under the documented invocation
-# ``PYTHONPATH=src python benchmarks/run.py`` (script mode puts only
-# benchmarks/ itself on sys.path).
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+sys.path.insert(0, os.path.join(ROOT, "src"))
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+from repro import jax_cache  # noqa: E402  (imports no JAX)
 
-jax.config.update("jax_platforms", "cpu")
-
-# Every _row lands here; ``--json`` serialises it at exit.
+# Every relayed row lands here; ``--json`` serialises it at exit.
 RESULTS: list[dict] = []
-
-
-def _timeit(fn, *args, warmup=2, iters=5):
-    for _ in range(warmup):
-        jax.block_until_ready(fn(*args))
-    best = float("inf")
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        best = min(best, time.perf_counter() - t0)
-    return best * 1e6  # us
 
 
 def _parse_derived(derived: str) -> dict:
@@ -88,293 +81,53 @@ def _row(name, us, derived=""):
     })
 
 
-# ---------------------------------------------------------------------------
-# Polybench (paper Fig. 6)
-# ---------------------------------------------------------------------------
+# section -> (argv after the interpreter, relayed row prefixes).  The
+# multi-device scripts force their own 8 virtual devices.
+SECTIONS = {
+    "polybench": (["sections.py", "polybench"], ("polybench_",)),
+    "region": (["region_chains.py"], ("region_",)),
+    "stencil_halo": (["stencil_halo.py"],
+                     ("stencil_halo_", "stencil_multifield_")),
+    "heat2d": (["heat2d.py"], ("heat2d_",)),
+    "roofline": (["roofline.py"], ("roofline_",)),
+    "compile_cache": (["sections.py", "compile_cache"],
+                      ("compile_cache_",)),
+    "serving": (["serving_load.py"], ("serving_",)),
+    "resilience": (["resilience.py"], ("resilience_",)),
+    "kernels": (["sections.py", "kernels"], ("kernels_",)),
+    "lm": (["sections.py", "lm"], ("loss_", "decode_")),
+}
 
 
-def _projected_speedup(programs, env, ranks=64, flops_time_us=None):
-    """T_1 / (T_1/P + comm/link_bw): the Fig. 6 projection."""
-    from repro.core.plan import make_plan
-    from repro.core.report import _comm_summary
-
-    comm_bytes = 0
-    for prog in programs:
-        plan = make_plan(prog, env, ranks)
-        line = _comm_summary(plan)[-1]
-        comm_bytes += int(line.split("~")[1].split()[0])
-    t1 = (flops_time_us or 1.0) * 1e-6
-    tp = t1 / ranks + comm_bytes / 50e9
-    return t1 / tp
-
-
-def bench_polybench():
-    from benchmarks.polybench import ALL_KERNELS
-    from repro import omp
-    from repro.compat import make_mesh
-
-    mesh = make_mesh((len(jax.devices()),), ("data",))
-
-    for make in ALL_KERNELS:
-        k = make()
-        env = k.env_fn(k.n)
-
-        def run_seq(env=env, k=k):
-            out = dict(env)
-            for prog in k.programs:
-                # sequential: lax.map over iterations (one at a time)
-                loop_out = out
-                t = prog.stop - prog.start
-                idx = prog.start + jnp.arange(t) * prog.step
-                vals = jax.lax.map(lambda i: prog.body(i, loop_out), idx)
-                from repro.core import pragma, reduction as red_mod
-
-                for key, upd in vals.items():
-                    if isinstance(upd, pragma.At):
-                        loop_out[key] = loop_out[key].at[upd.idx].set(
-                            upd.value)
-                    elif isinstance(upd, pragma.Red):
-                        rop = red_mod.get_reduction(prog.reduction[key])
-                        folded = rop.local_fold(upd.value, 0)
-                        loop_out[key] = rop.pairwise(loop_out[key], folded)
-                out = loop_out
-            return out
-
-        def run_omp(env=env, k=k):
-            out = dict(env)
-            for prog in k.programs:
-                out = prog(out)
-            return out
-
-        dists = [omp.compile(p, mesh) for p in k.programs]
-
-        def run_mpi(env=env, dists=dists):
-            out = dict(env)
-            for d in dists:
-                out = d(out)
-            return out
-
-        seq_j = jax.jit(run_seq)
-        omp_j = jax.jit(run_omp)
-        mpi_j = jax.jit(run_mpi)
-
-        ref = omp_j(env)
-        got = mpi_j(env)
-        for key in k.check_keys:
-            np.testing.assert_allclose(np.asarray(got[key]),
-                                       np.asarray(ref[key]),
-                                       rtol=1e-3, atol=1e-3)
-
-        us_seq = _timeit(seq_j)
-        us_omp = _timeit(omp_j)
-        us_mpi = _timeit(mpi_j)
-        # Fig. 6 analogue: projected speed-up of the generated program on
-        # 64 ranks vs the SEQUENTIAL baseline (the paper's y-axis)
-        proj = _projected_speedup(k.programs, env, ranks=64,
-                                  flops_time_us=us_seq)
-        _row(f"polybench_{k.name}_seq", us_seq)
-        _row(f"polybench_{k.name}_omp", us_omp,
-             f"speedup_vs_seq={us_seq / us_omp:.2f}")
-        _row(f"polybench_{k.name}_mpi", us_mpi,
-             f"proj_speedup64_vs_seq={proj:.1f};overhead_vs_omp="
-             f"{us_mpi / us_omp:.2f}")
-
-
-# ---------------------------------------------------------------------------
-# Region fusion (EXPERIMENTS.md §Perf-C)
-# ---------------------------------------------------------------------------
-
-
-def _bench_subprocess(script: str, prefix: str, row_name: str):
-    """Run a multi-device benchmark script in a subprocess (it forces its
-    own 8 virtual devices while this process already initialised jax on
-    the single real one) and relay its CSV rows.  ``prefix`` may be one
-    prefix or a tuple; ``row_name`` labels the failure row when the
-    script dies."""
-    import os
-    import subprocess
-    import sys
-
-    here = os.path.dirname(os.path.abspath(__file__))
+def run_section(name: str, meta: dict) -> bool:
+    """Run one section in a child and relay its rows; ``#`` lines fill
+    ``meta``.  Returns whether the child succeeded."""
+    argv, prefixes = SECTIONS[name]
     env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(here), "src")
-    env.pop("XLA_FLAGS", None)  # the script forces its own device count
+    env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env.pop("XLA_FLAGS", None)      # scripts force their own device count
     try:
         proc = subprocess.run(
-            [sys.executable, os.path.join(here, script)],
-            capture_output=True, text=True, env=env, timeout=560,
-        )
+            [sys.executable, os.path.join(HERE, argv[0])] + argv[1:],
+            capture_output=True, text=True, env=env, timeout=1800)
     except subprocess.TimeoutExpired:
-        _row(row_name, 0.0, "failed:timeout")
-        return
-    if proc.returncode != 0:
-        _row(row_name, 0.0, f"failed:{proc.stderr[-200:]!r}")
-        return
+        _row(name, 0.0, "failed:timeout")
+        return False
     for line in proc.stdout.splitlines():
-        if line.startswith(prefix):
-            name, us, derived = line.split(",", 2)
-            _row(name, float(us), derived)
+        if line.startswith("#device_count "):
+            meta["device_count"] = int(line.split()[1])
+        elif line.startswith("#compile_cache "):
+            meta["compile_cache"] = json.loads(line.split(" ", 1)[1])
+        elif line.startswith(prefixes):
+            row, us, derived = line.split(",", 2)
+            _row(row, float(us), derived)
+    if proc.returncode != 0:
+        _row(name, 0.0, f"failed:{proc.stderr[-200:]!r}")
+        return False
+    return True
 
 
-def bench_region():
-    """Multi-loop chains: fused region vs per-loop staging."""
-    _bench_subprocess("region_chains.py", "region_", "region_chains")
-
-
-def bench_stencil_halo():
-    """Cost-modeled halo boundaries vs the all-gather rule
-    (EXPERIMENTS.md §Perf-D) plus the multi-field aggregated schedule
-    vs the inline per-buffer rings (§Perf-G)."""
-    _bench_subprocess("stencil_halo.py",
-                      ("stencil_halo_", "stencil_multifield_"),
-                      "stencil_halo")
-
-
-def bench_heat2d():
-    """2-D five-point heat: row+column halo rings vs all-gather over a
-    4x2 mesh (EXPERIMENTS.md §Perf-E)."""
-    _bench_subprocess("heat2d.py", "heat2d_", "heat2d")
-
-
-def bench_roofline():
-    """Pallas-vs-lax chunk compute on the stencil acceptance shapes
-    (EXPERIMENTS.md §Perf-H; interpret mode on CPU — the committed
-    benchmarks/BENCH_pallas.json is this section's --json payload)."""
-    _bench_subprocess("roofline.py", "roofline_", "roofline")
-
-
-def bench_serving():
-    """Compile-and-serve: cross-process AOT warm start + concurrent
-    client load (EXPERIMENTS.md §Perf-I).  Subprocessed because the
-    cross-process phase spawns its own cold/warm children."""
-    _bench_subprocess("serving_load.py", "serving_", "serving_load")
-
-
-def bench_resilience():
-    """Fault-tolerant runtime: injection-hook / retry-wrapper overhead,
-    cold vs warm degraded-mesh recovery (the >= 5x warm-AOT bar), and
-    the straggler-weighted schedule cost (EXPERIMENTS.md §Perf-J; the
-    committed benchmarks/BENCH_resilience.json is this section's --json
-    payload)."""
-    _bench_subprocess("resilience.py", "resilience_", "resilience")
-
-
-# ---------------------------------------------------------------------------
-# Compilation cache (omp.compile cold vs warm)
-# ---------------------------------------------------------------------------
-
-# Filled by bench_compile_cache; serialised as the ``compile_cache``
-# section of the --json payload.
-COMPILE_CACHE: dict = {}
-
-
-def bench_compile_cache():
-    """Cold vs warm ``omp.compile``: the structural compilation cache
-    must make repeated compiles (benchmark sweeps, the differential
-    harness) skip re-planning entirely."""
-    from benchmarks.polybench import ALL_KERNELS
-    from repro import omp
-    from repro.compat import make_mesh
-
-    mesh = make_mesh((len(jax.devices()),), ("data",))
-    cold_us = warm_us = 0.0
-    n_programs = 0
-    omp.clear_compile_cache()
-    for make in ALL_KERNELS:
-        k = make()
-        env = k.env_fn(k.n)
-        for prog in k.programs:
-            n_programs += 1
-            t0 = time.perf_counter()
-            omp.compile(prog, mesh, env_like=env)
-            cold_us += (time.perf_counter() - t0) * 1e6
-            t0 = time.perf_counter()
-            c = omp.compile(prog, mesh, env_like=env)
-            warm_us += (time.perf_counter() - t0) * 1e6
-            assert c.cache_hit, f"warm compile of {prog.name} missed the cache"
-            env = prog(env)  # next block sees this block's outputs
-    stats = omp.compile_cache_stats()
-    speedup = cold_us / max(warm_us, 1e-9)
-    COMPILE_CACHE.update({
-        "n_programs": n_programs,
-        "cold_us_total": round(cold_us, 1),
-        "warm_us_total": round(warm_us, 1),
-        "speedup": round(speedup, 1),
-        "hits": stats["hits"],
-        "misses": stats["misses"],
-    })
-    _row("compile_cache_cold", cold_us / n_programs,
-         f"programs={n_programs}")
-    _row("compile_cache_warm", warm_us / n_programs,
-         f"speedup={speedup:.1f};hits={stats['hits']};"
-         f"misses={stats['misses']}")
-
-
-# ---------------------------------------------------------------------------
-# Pallas kernels
-# ---------------------------------------------------------------------------
-
-
-def bench_kernels():
-    from repro.kernels import ops, ref
-
-    rng = np.random.default_rng(0)
-    b, s, h, kv, hd = 1, 256, 4, 2, 64
-    q = jnp.asarray(rng.normal(size=(b, s, h, hd)).astype(np.float32))
-    k = jnp.asarray(rng.normal(size=(b, s, kv, hd)).astype(np.float32))
-    v = jnp.asarray(rng.normal(size=(b, s, kv, hd)).astype(np.float32))
-    us = _timeit(lambda: ops.flash_attention(q, k, v, kind="causal"))
-    ref_us = _timeit(jax.jit(lambda: ref.flash_attention_ref(
-        q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2))))
-    _row("kernels_flash_attention_interp", us,
-         f"oracle_us={ref_us:.0f}")
-
-    x = jnp.asarray(rng.normal(size=(1, 256, 2, 32)).astype(np.float32))
-    dt = jnp.abs(jnp.asarray(rng.normal(size=(1, 256, 2))
-                             .astype(np.float32))) * 0.1
-    A = jnp.asarray((-np.abs(rng.normal(size=(2,))) - 0.1)
-                    .astype(np.float32))
-    Bm = jnp.asarray(rng.normal(size=(1, 256, 16)).astype(np.float32))
-    Cm = jnp.asarray(rng.normal(size=(1, 256, 16)).astype(np.float32))
-    D = jnp.asarray(rng.normal(size=(2,)).astype(np.float32))
-    us = _timeit(lambda: ops.ssd_scan(x, dt, A, Bm, Cm, D, chunk=64))
-    ref_us = _timeit(jax.jit(lambda: ref.ssd_ref(x, dt, A, Bm, Cm, D)[0]))
-    _row("kernels_ssd_scan_interp", us, f"oracle_us={ref_us:.0f}")
-
-
-# ---------------------------------------------------------------------------
-# LM steps (smoke size)
-# ---------------------------------------------------------------------------
-
-
-def bench_lm_steps():
-    from repro.configs import smoke_config
-    from repro.models import build_model
-
-    for arch in ("gemma3-1b", "mamba2-130m", "qwen2-moe-a2.7b"):
-        cfg = smoke_config(arch)
-        model = build_model(cfg)
-        params, _ = model.init(jax.random.PRNGKey(0))
-        b, s = 2, 128
-        key = jax.random.PRNGKey(1)
-        batch = {"tokens": jax.random.randint(key, (b, s), 0,
-                                              cfg.vocab_size),
-                 "labels": jax.random.randint(key, (b, s), 0,
-                                              cfg.vocab_size)}
-        loss_j = jax.jit(lambda p, bt: model.loss_fn(p, bt)[0])
-        us = _timeit(loss_j, params, batch)
-        _row(f"loss_{arch}", us, f"tokens={b * s}")
-
-        cache = model.init_cache(b, 64, dtype=jnp.float32)
-        dec_j = jax.jit(lambda p, c, t, q: model.decode_step(p, c, t, q))
-        tok = jnp.zeros((b,), jnp.int32)
-        pos = jnp.full((b,), 1, jnp.int32)
-        # decode donates nothing here; measure steady-state step
-        us = _timeit(dec_j, params, cache, tok, pos)
-        _row(f"decode_{arch}", us, "cache_len=64")
-
-
-def main(argv=None) -> None:
+def main(argv=None) -> int:
     import argparse
 
     parser = argparse.ArgumentParser(description=__doc__)
@@ -385,44 +138,30 @@ def main(argv=None) -> None:
     parser.add_argument(
         "--sections", default=None,
         help="comma-separated subset of sections to run "
-             "(polybench,region,stencil_halo,heat2d,roofline,"
-             "compile_cache,serving,resilience,kernels,lm)")
+             f"({','.join(SECTIONS)})")
     args = parser.parse_args(argv)
 
-    sections = {
-        "polybench": bench_polybench,
-        "region": bench_region,
-        "stencil_halo": bench_stencil_halo,
-        "heat2d": bench_heat2d,
-        "roofline": bench_roofline,
-        "compile_cache": bench_compile_cache,
-        "serving": bench_serving,
-        "resilience": bench_resilience,
-        "kernels": bench_kernels,
-        "lm": bench_lm_steps,
-    }
     wanted = (args.sections.split(",") if args.sections
-              else list(sections))
-    unknown = [s for s in wanted if s not in sections]
+              else list(SECTIONS))
+    unknown = [s for s in wanted if s not in SECTIONS]
     if unknown:
         parser.error(f"unknown sections {unknown}; pick from "
-                     f"{sorted(sections)}")
+                     f"{sorted(SECTIONS)}")
 
+    jax_cache.enable()
     print("name,us_per_call,derived")
-    for name in wanted:
-        sections[name]()
+    meta: dict = {}
+    failed = [name for name in wanted if not run_section(name, meta)]
 
     if args.json:
-        import json
-
         payload = {
             "schema": "repro-bench-v1",
-            "device_count": len(jax.devices()),
+            "device_count": meta.get("device_count"),
             "sections": wanted,
             "results": RESULTS,
         }
-        if COMPILE_CACHE:   # only when the compile_cache section ran
-            payload["compile_cache"] = COMPILE_CACHE
+        if "compile_cache" in meta:  # only when the section ran
+            payload["compile_cache"] = meta["compile_cache"]
         # The communication snapshot: every row that carries collective
         # ops / wire-byte / launch counters, so the perf trajectory of
         # the comm planner + scheduler is recorded run over run (the
@@ -453,7 +192,11 @@ def main(argv=None) -> None:
         with open(args.json, "w", encoding="utf-8") as fh:
             json.dump(payload, fh, indent=2)
         print(f"# wrote {len(RESULTS)} results to {args.json}", flush=True)
+    if failed:
+        print(f"# failed sections: {','.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -17,6 +17,9 @@ Run directly (``PYTHONPATH=src python benchmarks/serving_load.py``) or
 through ``benchmarks/run.py --sections serving`` (which subprocesses
 it).  The committed ``benchmarks/BENCH_serving.json`` is the
 ``--sections serving --json`` payload.
+
+Both phases run in children (``--child`` and ``--load``): this process
+never imports JAX, so no parent holds a device a child needs.
 """
 from __future__ import annotations
 
@@ -30,9 +33,12 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
+def _cpu_jax():
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    return jax
 
 
 # ---------------------------------------------------------------------------
@@ -43,6 +49,7 @@ jax.config.update("jax_platforms", "cpu")
 def _child(cache_dir: str) -> None:
     # REPRO_AOT_CACHE_DIR was set by the parent before we imported repro,
     # so the persistent store is already enabled.
+    jax = _cpu_jax()
     from benchmarks.polybench import ALL_KERNELS
     from repro import omp
     from repro.compat import make_mesh
@@ -70,22 +77,23 @@ def _child(cache_dir: str) -> None:
                       "disk_errors": stats["disk_errors"]}))
 
 
-def _run_child(cache_dir: str) -> dict:
-    env = dict(os.environ, REPRO_AOT_CACHE_DIR=cache_dir)
+def _run_child(*args: str, env_extra: dict | None = None) -> str:
+    env = dict(os.environ, **(env_extra or {}))
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
     proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--child", cache_dir],
+        [sys.executable, os.path.abspath(__file__), *args],
         capture_output=True, text=True, env=env, timeout=540)
     if proc.returncode != 0:
         raise RuntimeError(f"child failed: {proc.stderr[-400:]}")
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return proc.stdout
 
 
 def bench_cross_process() -> None:
     with tempfile.TemporaryDirectory(prefix="repro-aot-bench-") as d:
-        cold = _run_child(d)
-        warm = _run_child(d)
+        cold, warm = (json.loads(_run_child(
+            "--child", d, env_extra={"REPRO_AOT_CACHE_DIR": d})
+            .strip().splitlines()[-1]) for _ in range(2))
     n = cold["programs"]
     speedup = cold["total_s"] / max(warm["total_s"], 1e-9)
     print(f"serving_cold_process,{cold['total_s'] * 1e6 / n:.1f},"
@@ -106,6 +114,7 @@ def bench_cross_process() -> None:
 
 
 def bench_concurrent_load(n_threads: int = 8, sweeps: int = 3) -> None:
+    jax = _cpu_jax()
     from benchmarks.polybench import ALL_KERNELS
     from repro import omp
     from repro.compat import make_mesh
@@ -160,9 +169,12 @@ def main() -> None:
     if len(sys.argv) > 1 and sys.argv[1] == "--child":
         _child(sys.argv[2])
         return
+    if len(sys.argv) > 1 and sys.argv[1] == "--load":
+        bench_concurrent_load()
+        return
     print("name,us_per_call,derived")
     bench_cross_process()
-    bench_concurrent_load()
+    print(_run_child("--load"), end="", flush=True)
 
 
 if __name__ == "__main__":

@@ -1,57 +1,26 @@
-"""JAX version compatibility shims.
+"""The two JAX entry points every lowering shares.
 
-The repo targets the modern JAX surface (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``,
-``jax.sharding.AxisType``) but must also run on the 0.4.x line where
-
-* ``shard_map`` lives in ``jax.experimental.shard_map`` and the
-  replication-check kwarg is ``check_rep``,
-* ``jax.make_mesh`` exists but does not accept ``axis_types``,
-* ``jax.sharding.AxisType`` does not exist.
-
-Everything in the repo goes through these two helpers instead of calling
-the moving targets directly.  No behaviour difference is intended: the
-meshes are always fully "auto" (GSPMD-managed) and the shard_map
-replication checker is always disabled (the master/worker lowering is
-deliberately rank-divergent).
+Meshes are always fully "auto" (GSPMD-managed) and the shard_map
+replication checker is always off (the master/worker lowering is
+deliberately rank-divergent); these wrappers pin both choices in one
+place.
 """
 from __future__ import annotations
 
 from typing import Any, Sequence
 
 import jax
-
-try:  # modern JAX
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # 0.4.x
-    _AxisType = None
-
-AxisType = _AxisType
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with explicit Auto axis types where supported."""
-    if _AxisType is not None:
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(_AxisType.Auto,) * len(tuple(axis_names)),
-        )
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, *, mesh, in_specs, out_specs) -> Any:
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_04x
-
-    def shard_map(f, *, mesh, in_specs, out_specs) -> Any:
-        return _shard_map_04x(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+def shard_map(f, *, mesh, in_specs, out_specs) -> Any:
+    """``jax.shard_map`` without the replication check."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

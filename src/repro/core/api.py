@@ -108,8 +108,11 @@ class Lowering(enum.Enum):
     """The FUSED lowering with each compute span — a stage's chunk
     loop, or a chain of stages between scheduled exchanges — emitted as
     one tiled Pallas kernel over the local slab
-    (:mod:`repro.core.pallas_lower`).  Interpret-mode fallback off-TPU;
-    see ``Options.pallas_interpret``."""
+    (:mod:`repro.core.pallas_lower`).  Kernels serve reads from chunk
+    windows, so single blocks slice their inputs as regions do.  Off-TPU
+    the kernels run in interpret mode (see ``Options.pallas_interpret``);
+    on a TPU mesh every span compiles for the chip, or ``omp.compile``
+    raises :class:`CompileError` naming the span."""
 
 
 class CommMode(enum.Enum):
@@ -230,8 +233,8 @@ class Options:
     pallas_interpret: bool | None = None
     """Pallas execution mode for ``Lowering.PALLAS``: ``None`` (default)
     runs the kernels in interpret mode off-TPU (CPU/CI) and compiled on
-    TPU; ``True``/``False`` forces.  Rejected under any other
-    lowering."""
+    TPU; ``True``/``False`` forces.  ``True`` is rejected on a TPU mesh,
+    and the field under any other lowering."""
 
     chunk_weights: Any = None
     """Per-device speed weights for a straggler-weighted schedule
@@ -595,7 +598,7 @@ def compile(
             f"{type(program).__name__}")
 
     axis, num = tf.resolve_axes(program, mesh, options.axis)
-    _validate_combination(program, options, num)
+    _validate_combination(program, options, num, mesh)
     compiled = Compiled(program=program, mesh=mesh, options=options,
                         axis=axis, num_devices=num)
     if env_like is not None:
@@ -603,10 +606,19 @@ def compile(
     return compiled
 
 
-def _validate_combination(program, options: Options, num) -> None:
+def _on_tpu(mesh) -> bool:
+    return mesh.devices.flat[0].platform == "tpu"
+
+
+def _validate_combination(program, options: Options, num, mesh) -> None:
     """Cross-field validation that needs the program: one diagnostics
     path instead of ad-hoc raises scattered through the lowerings."""
     rank = program.rank
+    if options.pallas_interpret and _on_tpu(mesh):
+        raise CompileError(
+            "Options.pallas_interpret=True on a TPU mesh: Pallas spans "
+            "run compiled on the chip, never in interpret mode.  Drop "
+            "pallas_interpret.")
     if options.lowering is Lowering.MASTER_WORKER:
         if rank == 2:
             raise CompileError(
@@ -691,7 +703,11 @@ def _pallas_pass(options: Options, kernel_plan) -> tuple:
 
 def _build_block(program, env_shapes, num, axis, options) -> _Artifacts:
     low = _lowering_str(options)
-    shard_inputs = options.shard is ShardPolicy.SLICE
+    # a Pallas kernel serves x[i]-style reads from chunk windows only (a
+    # read of a replicated buffer would be a gather), so Pallas blocks
+    # slice their inputs as fused regions always do
+    shard_inputs = (options.shard is ShardPolicy.SLICE
+                    or options.lowering is Lowering.PALLAS)
     nest, ctx = plan_mod.analyze_program(program, env_shapes)
     chunks_axes = plan_mod.plan_schedule(
         program, nest, num, lowering=low,
@@ -897,6 +913,12 @@ def _make_executor(program, mesh, axis, options: Options, exe_plan):
         chunk_weights=options.chunk_weights)
 
 
+def _sig_avals(sig: tuple) -> dict:
+    return {k: jax.ShapeDtypeStruct(
+                tuple(sh), jax.dtypes.canonicalize_dtype(np.dtype(dt)))
+            for k, sh, dt in sig}
+
+
 def _export_and_save(dkey: str, exe, sig: tuple):
     """AOT-lower the executor end-to-end (jit → lower → XLA compile)
     and persist the serialized executable under ``dkey``.  Returns the
@@ -904,11 +926,9 @@ def _export_and_save(dkey: str, exe, sig: tuple):
     ``None`` when the program cannot be staged out (e.g. host-side
     serial glue in a staged region): those fall back to the per-call
     jit path, exactly as before persistence existed."""
-    avals = {k: jax.ShapeDtypeStruct(
-                 tuple(sh), jax.dtypes.canonicalize_dtype(np.dtype(dt)))
-             for k, sh, dt in sig}
     try:
-        compiled = jax.jit(lambda env: dict(exe(env))).lower(avals).compile()
+        compiled = jax.jit(lambda env: dict(exe(env))).lower(
+            _sig_avals(sig)).compile()
     except Exception:
         return None
     _PERSISTENT.save(dkey, compiled)
@@ -1058,6 +1078,10 @@ class Compiled:
         self._exe = exe
         self._env_sig = sig
         self._runner = None
+        if self.options.lowering is Lowering.PALLAS and _on_tpu(self.mesh):
+            # tracing compiles every span for the chip: a span Mosaic
+            # refuses raises CompileError here, not at the first call
+            jax.eval_shape(lambda env: dict(exe(env)), _sig_avals(sig))
 
     def _disk_key(self, sig: tuple) -> str:
         return aot_store_mod.fingerprint(

@@ -316,8 +316,10 @@ def local_slabs2(x, chs, halos, device_indices):
 # rule: 8 sublanes of 32-bit lanes, narrower dtypes pack 2x/4x deeper).
 _SUBLANE_BY_ITEMSIZE = {8: 8, 4: 8, 2: 16, 1: 32}
 
-# Lanes per kernel tile are capped so one tile's window + values stay
-# comfortably inside VMEM whatever the chunk size.
+# Lanes per kernel tile are capped so one tile's values stay comfortably
+# inside VMEM whatever the chunk size.  A multiple of 128: a chunk split
+# into several tiles then has lane-aligned tiles, as Mosaic requires of
+# a block's minor dimension (one tile per chunk spans the axis instead).
 MAX_TILE_LANES = 256
 
 

@@ -17,11 +17,12 @@ Geometry (per axis) comes from the chunk-cyclic layout owned by
 :mod:`repro.core.nest`: chunk ``j = q*P + d`` starts at ``k0 = j*c``;
 its ``c`` lanes tile as :class:`~repro.core.nest.AxisTiles` (sublane
 rounding per dtype, masked remainder lanes clamp to the last in-bounds
-iteration exactly like the trip padding).  Window inputs enter as
-full-chunk blocks ``(1, w, *rest)`` indexed ``(q, 0)`` — halo windows
-overlap between chunks, so halo-awareness lives in the in-kernel row
-offset ``pos - (k0 + b_min)`` rather than in the BlockSpec — and
-outputs leave as ``(1, tile, *rest)`` blocks indexed ``(q, ti)``.
+iteration exactly like the trip padding).  Each chunk's window (the
+rows it reads, halo included) enters as one block indexed by the chunk,
+with the chunk index squeezed away; halo windows overlap between chunks,
+so halo-awareness lives in the static offset of each read
+``x[i+b]`` inside the block (``b - b_min``) rather than in the BlockSpec.
+Outputs leave as one block per tile.
 
 The kernel produces only dense per-lane body values; every merge
 (scatter/put/reduce folds, slab state updates, cross-device combines)
@@ -30,9 +31,10 @@ which reproduces the ``(carry, ys)`` contract of ``_run_local_chunks``
 bit-for-bit — that is what lets the differential test wall pin the
 backend against the lax lowering and the shared-memory reference.
 
-On CPU (this container, CI) the kernels run in interpret mode;
+Off-TPU (the CPU tests) the kernels run in interpret mode;
 ``Options(pallas_interpret=...)`` forces either mode, ``None`` picks
-interpret off-TPU.
+interpret off-TPU.  On a TPU every span is first compiled on its own
+(:func:`_preflight`), so a span Mosaic refuses raises ``CompileError``.
 """
 from __future__ import annotations
 
@@ -42,9 +44,12 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from jax.extend import core as jcore
+
+from repro.core import context as ctx_mod
 from repro.core import nest as nest_mod
 from repro.core import reduction as red_mod
-from repro.core.nest import AxisTiles, ShiftedWindow, derive_axis_tiles
+from repro.core.nest import AxisTiles, NestAffine, derive_axis_tiles
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -242,14 +247,10 @@ def plan_region_kernels(rp) -> KernelPlan:
 
 
 def resolve_interpret(option, mesh) -> bool:
-    """None -> interpret off-TPU (CPU/CI fallback); True/False forces."""
+    """None -> interpret off-TPU (the CPU test path); True/False forces."""
     if option is not None:
         return bool(option)
-    try:
-        platform = mesh.devices.flat[0].platform
-    except Exception:  # pragma: no cover - defensive
-        platform = jax.default_backend()
-    return platform != "tpu"
+    return mesh.devices.flat[0].platform != "tpu"
 
 
 @dataclasses.dataclass
@@ -272,189 +273,468 @@ def _halo_base(dec, axis: int = 0) -> int:
     return dec.halo[0] if dec.halo is not None else 0
 
 
+# VMEM per TensorCore, by device kind (the figures of
+# ``jax.experimental.pallas.tpu.get_tpu_info``).  A kernel asks for three
+# quarters of it; other kinds keep the compiler's default scoped limit.
+_VMEM_BYTES = {"TPU v5 lite": 128 << 20, "TPU v5e": 128 << 20,
+               "TPU v6 lite": 128 << 20, "TPU v6e": 128 << 20,
+               "TPU v5": 64 << 20, "TPU v5p": 64 << 20}
+
+
+# ---------------------------------------------------------------------------
+# Tile evaluation of a loop body
+#
+# The body is traced once on scalar iterators (as Context Analysis traces
+# it) and its jaxpr is evaluated over a whole tile: every equation runs
+# batched over the tile's lanes through ``jax.vmap``, except the reads
+# ``x[i+b]`` / ``x[i+b, j+c]`` of a chunk window, which become static
+# slices of the loaded block.  A vmapped body would turn those reads into
+# gathers, which Mosaic cannot lower.
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class _Body:
+    closed: Any                # ClosedJaxpr of body(i[, j], env)
+    env_keys: tuple            # env keys in invar order
+    value_pos: dict            # written key -> flat position of its value
+
+
+def _trace_body(plan, program) -> _Body:
+    infos = plan.context.vars
+    keys = tuple(sorted(plan.context.env_keys))
+    env = {k: jax.ShapeDtypeStruct(infos[k].shape, infos[k].dtype)
+           for k in keys}
+    it = jax.ShapeDtypeStruct((), jnp.int32)
+    closed, out_shape = jax.make_jaxpr(program.body, return_shape=True)(
+        *(it,) * plan.rank, env)
+    leaves, tree = jax.tree_util.tree_flatten(out_shape)
+    pos = jax.tree_util.tree_unflatten(tree, list(range(len(leaves))))
+    return _Body(closed, keys, {k: u.value for k, u in pos.items()})
+
+
+@dataclasses.dataclass
+class _Src:
+    """An env buffer as the kernel sees it: ``win`` (the tile's region of
+    a chunk window, or a forwarded tile; sharded axes lead and row 0 of
+    axis ``d`` is lane 0 shifted by ``origin[d]``), ``val`` (a replicated
+    array) or ``zeros`` (a buffer the stage never reads)."""
+
+    kind: str
+    value: Any = None
+    origin: tuple = ()
+    info: Any = None
+
+
+@dataclasses.dataclass
+class _Read:
+    """A served window read whose unit per-lane axes (the ``r`` sharded
+    ones) are not materialised yet: the ``squeeze`` that jnp indexing
+    emits next drops them for free."""
+
+    value: Any
+    mask: tuple
+    r: int
+
+
+def _is_var(v) -> bool:
+    return isinstance(v, jcore.Var)
+
+
+def _live_eqns(jaxpr, want, windows) -> set:
+    live = {v for v in want if _is_var(v)}
+    keep = set()
+    for n in range(len(jaxpr.eqns) - 1, -1, -1):
+        eqn = jaxpr.eqns[n]
+        if not any(ov in live for ov in eqn.outvars):
+            continue
+        keep.add(n)
+        ins = eqn.invars
+        if eqn.primitive.name == "dynamic_slice" and ins[0] in windows:
+            ins = ins[:1]                   # served as a static slice
+        live.update(v for v in ins if _is_var(v))
+    return keep
+
+
+def _apply_batched(eqn, ins, nax: int):
+    """One equation over batched operands (leading dims = the batched
+    lane axes, in axis order): nested ``jax.vmap``, outermost axis 0."""
+    subfuns, params = eqn.primitive.get_bind_params(eqn.params)
+
+    def f(*args):
+        return eqn.primitive.bind(*subfuns, *args, **params)
+
+    masks = [m for _, m in ins]
+    out_mask = tuple(any(m[a] for m in masks) for a in range(nax))
+    g = f
+    for a in reversed(range(nax)):
+        if out_mask[a]:
+            g = jax.vmap(g, in_axes=tuple(0 if m[a] else None
+                                          for m in masks))
+    return g(*(x for x, _ in ins)), out_mask
+
+
+def _serve_read(eqn, src: _Src, aff, plan, lanes):
+    """``dynamic_slice(x, i+b, ...)`` of a window -> the tile's slice."""
+    nax = len(lanes)
+    starts = [aff.lookup(a) for a in eqn.invars[1:]]
+    sizes = eqn.params["slice_sizes"]
+    r = len(src.origin)
+    v = src.value
+    for d in range(r):
+        unit = tuple(int(a == d) for a in range(nax))
+        km = starts[d].k_space(plan.nest) if starts[d] is not None else None
+        s = None if km is None or km.coeffs != unit else km.b - src.origin[d]
+        if sizes[d] != 1 or s is None or s < 0 \
+                or s + lanes[d] > v.shape[d]:
+            raise nest_mod.SubstitutionFailed(
+                f"read of axis {d} at {starts[d]!r} is not a unit-stride "
+                "read inside the chunk window")
+        v = jax.lax.slice_in_dim(v, s, s + lanes[d], axis=d)
+    for d in range(r, len(sizes)):
+        m, size, dim = starts[d], sizes[d], v.shape[d]
+        if m is None or not m.is_const:
+            raise nest_mod.SubstitutionFailed(
+                f"read of unsharded axis {d} at {m!r} is not constant")
+        if size != dim:
+            c = min(max(m.b, 0), dim - size)    # dynamic_slice clamps
+            v = jax.lax.slice_in_dim(v, c, c + size, axis=d)
+    v = v.astype(eqn.outvars[0].aval.dtype)
+    return _Read(v, tuple(a < r for a in range(nax)), r)
+
+
+def _materialize(rd: _Read):
+    nb = sum(rd.mask)
+    v = rd.value
+    return v.reshape(v.shape[:nb] + (1,) * rd.r + v.shape[nb:]), rd.mask
+
+
+def _full(v, mask, lanes):
+    """Broadcast a batched value over every lane axis."""
+    v = jnp.asarray(v)
+    for a, m in enumerate(mask):
+        if not m:
+            v = jnp.expand_dims(v, a)
+    return jnp.broadcast_to(v, tuple(lanes) + v.shape[len(lanes):])
+
+
+def _eval_body(body: _Body, plan, ivals, srcs, lanes, keys_out) -> dict:
+    """Evaluate a loop body over one tile; returns ``key -> (value,
+    batch mask)`` for every written key in ``keys_out``."""
+    jaxpr = body.closed.jaxpr
+    nax = len(lanes)
+    none = (False,) * nax
+    vals: dict = {}
+    for v, c in zip(jaxpr.constvars, body.closed.consts):
+        vals[v] = (c, none)
+    for v, iv in zip(jaxpr.invars[:nax], ivals):
+        vals[v] = iv
+    src_of = dict(zip(jaxpr.invars[nax:], (srcs[k] for k in body.env_keys)))
+    want = [jaxpr.outvars[body.value_pos[k]] for k in keys_out]
+    windows = {v for v, src in src_of.items() if src.kind == "win"}
+    live = _live_eqns(jaxpr, want, windows)
+    aff = ctx_mod._AffineEnv(
+        {v: NestAffine(tuple(int(a == d) for a in range(nax)), 0)
+         for d, v in enumerate(jaxpr.invars[:nax])},
+        const=lambda c: NestAffine((0,) * nax, c))
+
+    def read(v):
+        if not _is_var(v):
+            return v.val, none
+        src = src_of.get(v)
+        if src is not None:
+            if src.kind == "val":
+                return src.value, none
+            if src.kind == "zeros":
+                return jnp.zeros(src.info.shape, src.info.dtype), none
+            raise nest_mod.SubstitutionFailed(
+                "a chunk-window buffer is used other than through "
+                "x[i]-style reads")
+        got = vals[v]
+        return _materialize(got) if isinstance(got, _Read) else got
+
+    for n, eqn in enumerate(jaxpr.eqns):
+        aff.process(eqn)
+        if n not in live:
+            continue
+        prim = eqn.primitive.name
+        x0 = eqn.invars[0] if eqn.invars else None
+        if prim == "dynamic_slice" and x0 in windows:
+            vals[eqn.outvars[0]] = _serve_read(eqn, src_of[x0], aff, plan,
+                                               lanes)
+            continue
+        pending = vals.get(x0) if _is_var(x0) else None
+        dims = eqn.params.get("dimensions", ())
+        if prim == "squeeze" and isinstance(pending, _Read) \
+                and set(range(pending.r)) <= set(dims):
+            nb = sum(pending.mask)
+            rest = tuple(nb + d - pending.r for d in dims if d >= pending.r)
+            v = jax.lax.squeeze(pending.value, rest) if rest \
+                else pending.value
+            vals[eqn.outvars[0]] = (v, pending.mask)
+            continue
+        outs, mask = _apply_batched(eqn, [read(v) for v in eqn.invars], nax)
+        if not eqn.primitive.multiple_results:
+            outs = [outs]
+        for ov, o in zip(eqn.outvars, outs):
+            vals[ov] = (o, mask)
+    return {k: read(w) for k, w in zip(keys_out, want)}
+
+
+# ---------------------------------------------------------------------------
+# Kernel inputs and outputs
+#
+# Mosaic wants the last two dims of every block divisible by (sublane,
+# 128) or equal to the array's own, so each chunk's block is laid out with
+# the chunk index squeezed away in front: windows as ``(n, 1, L)`` (rows
+# of scalars) / ``(n, L, *rest)`` / ``(n_i, n_j, L_i, L_j, *rest)``,
+# outputs as ``(n, 1, padded)`` / ``(n, padded, *v)`` /
+# ``(n_i, n_j, padded_i, padded_j, *v)``.  ``derive_axis_tiles`` gives
+# either one tile per chunk (the block spans the axis) or 256-lane tiles,
+# so output blocks are always aligned.  A window is padded so every tile
+# loads an aligned region: the whole window with one tile per chunk,
+# otherwise ``tile + halo`` rows from an aligned dynamic start.
+# ---------------------------------------------------------------------------
+
+
+def _align(pos: int, ndim: int, dtype) -> int:
+    """Alignment a dynamic start needs on block dim ``pos`` of ``ndim``."""
+    if pos == ndim - 1:
+        return 128
+    if pos == ndim - 2:
+        return nest_mod.sublane_for(dtype)
+    return 1
+
+
+def _axis_load(tl: AxisTiles, halo_w: int, align: int) -> tuple:
+    """``(n_tiles, tile, align, rows loaded per tile)`` plus the padded
+    window length for one sharded axis."""
+    if tl.n_tiles == 1:
+        length = tl.padded + halo_w
+    else:
+        length = tl.tile + -(-halo_w // align) * align
+    return (tl.n_tiles, tl.tile, align, length), \
+        (tl.n_tiles - 1) * tl.tile + length
+
+
+@dataclasses.dataclass
+class _Input:
+    si: int
+    key: str
+    kind: str                  # "win" | "scalar" | "vec" | "arr"
+    array: Any
+    spec: Any
+    loads: tuple = ()          # win: _axis_load per sharded axis
+    lead: bool = False         # win: rows of scalars, laid out (n, 1, L)
+
+    def load(self, ref, ts):
+        if self.kind in ("scalar", "vec"):
+            return ref[0]
+        if self.kind == "arr":
+            return ref[...]
+        idx = [0] if self.lead else []
+        for t, (n, tile, align, length) in zip(ts, self.loads):
+            idx.append(slice(None) if n == 1 else
+                       pl.ds(pl.multiple_of(t * tile, align), length))
+        return ref[tuple(idx) + (slice(None),) * (len(ref.shape) - len(idx))]
+
+
+def _window_input(si, key, arr, two_d: bool, rank: int, tiles) -> _Input:
+    if two_d:                                # (n_i, w_i, n_j, w_j, *rest)
+        rest = arr.shape[4:]
+        pads = [(0, 0)] * arr.ndim
+        loads = []
+        for a, ax in ((0, 1), (1, 3)):
+            ld, total = _axis_load(tiles[a], arr.shape[ax] - tiles[a].chunk,
+                                   _align(a, 2 + len(rest), arr.dtype))
+            pads[ax] = (0, total - arr.shape[ax])
+            loads.append(ld)
+        arr = jnp.moveaxis(jnp.pad(arr, pads), 2, 1)
+        spec = pl.BlockSpec((None, None) + arr.shape[2:],
+                            lambda qi, qj, ti, tj: (qi, qj)
+                            + (0,) * (arr.ndim - 2))
+        return _Input(si, key, "win", arr, spec, tuple(loads))
+    rest = arr.shape[2:]                     # (n, w, *rest)
+    lead = not rest
+    ld, total = _axis_load(
+        tiles[0], arr.shape[1] - tiles[0].chunk,
+        _align(1 if lead else 0, 2 if lead else 1 + len(rest), arr.dtype))
+    arr = jnp.pad(arr, [(0, 0), (0, total - arr.shape[1])]
+                  + [(0, 0)] * len(rest))
+    if lead:
+        arr = arr.reshape(arr.shape[0], 1, total)
+    zeros = (0,) * (arr.ndim - 1)
+    imap = ((lambda q, t: (q,) + zeros) if rank == 1
+            else (lambda qi, qj, ti, tj: (qi,) + zeros))
+    return _Input(si, key, "win", arr, pl.BlockSpec((None,) + arr.shape[1:],
+                                                    imap), (ld,), lead)
+
+
+def _repl_input(si, key, arr) -> _Input:
+    arr = jnp.asarray(arr)
+    if arr.ndim == 0:
+        return _Input(si, key, "scalar", arr.reshape(1),
+                      pl.BlockSpec(memory_space=pltpu.SMEM))
+    kind = "vec" if arr.ndim == 1 else "arr"
+    if kind == "vec":
+        arr = arr.reshape(1, -1)
+    return _Input(si, key, kind, arr, pl.BlockSpec(
+        arr.shape, lambda *_g: (0,) * arr.ndim))
+
+
 def _collect_io(stages, rank: int, tiles):
-    """Input arrays/specs (after the SMEM meta scalar) and output
+    """Kernel inputs (after the SMEM meta scalars) and output
     shapes/specs, in stable order."""
-    n_grid = 2 if rank == 1 else 4
-
-    def zero_map(ndim):
-        return lambda *_g: (0,) * ndim
-
-    def win_map(ndim):                       # (q, 0, ...) full-chunk block
-        if rank == 1:
-            return lambda q, ti: (q,) + (0,) * (ndim - 1)
-        return lambda qi, qj, ti, tj: (qi,) + (0,) * (ndim - 1)
-
-    def win2_map(ndim):                      # (qi, 0, qj, 0, ...)
-        return lambda qi, qj, ti, tj: (qi, 0, qj) + (0,) * (ndim - 3)
-
-    def out_map(nrest):
-        if rank == 1:
-            return lambda q, ti: (q, ti) + (0,) * nrest
-        return lambda qi, qj, ti, tj: (qi, ti, qj, tj) + (0,) * nrest
-
-    del n_grid
-    inputs, in_specs, loaders = [], [], []
+    inputs = []
     for si, sp in enumerate(stages):
         for key in sorted(sp.ext_windows):
-            arr = sp.ext_windows[key]
             two_d = rank == 2 and getattr(sp.plan.vars[key],
                                           "shard_ndim", 1) == 2
-            if two_d:
-                blk = (1, arr.shape[1], 1, arr.shape[3]) + arr.shape[4:]
-                in_specs.append(pl.BlockSpec(blk, win2_map(arr.ndim)))
-            else:
-                blk = (1,) + arr.shape[1:]
-                in_specs.append(pl.BlockSpec(blk, win_map(arr.ndim)))
-            inputs.append(arr)
-            loaders.append(("win2" if two_d else "win", si, key))
+            inputs.append(_window_input(si, key, sp.ext_windows[key], two_d,
+                                        rank, tiles))
         for key in sorted(sp.env_repl):
-            arr = jnp.asarray(sp.env_repl[key])
-            kind = "scalar" if arr.ndim == 0 else "repl"
-            if arr.ndim == 0:
-                arr = arr.reshape(1)
-            in_specs.append(pl.BlockSpec(arr.shape, zero_map(arr.ndim)))
-            inputs.append(arr)
-            loaders.append((kind, si, key))
+            inputs.append(_repl_input(si, key, sp.env_repl[key]))
     out_shapes, out_specs, out_keys = [], [], []
     for si, sp in enumerate(stages):
         plan = sp.plan
         chs = plan.chunks_axes
         for key in sorted(plan.vars):
-            dec = plan.vars[key]
-            if dec.out_strategy == "none":
+            if plan.vars[key].out_strategy == "none":
                 continue
             info = plan.context.vars[key]
             vshape = tuple(info.write.value_shape)
-            vdt = info.write.value_dtype
-            if rank == 1:
+            vz = (0,) * len(vshape)
+            if rank == 1 and not vshape:
+                full = (chs[0].local_chunks, 1, tiles[0].padded)
+                spec = pl.BlockSpec((None, 1, tiles[0].tile),
+                                    lambda q, t: (q, 0, t))
+            elif rank == 1:
                 full = (chs[0].local_chunks, tiles[0].padded) + vshape
-                blk = (1, tiles[0].tile) + vshape
+                spec = pl.BlockSpec((None, tiles[0].tile) + vshape,
+                                    lambda q, t, vz=vz: (q, t) + vz)
             else:
-                full = (chs[0].local_chunks, tiles[0].padded,
-                        chs[1].local_chunks, tiles[1].padded) + vshape
-                blk = (1, tiles[0].tile, 1, tiles[1].tile) + vshape
-            out_shapes.append(jax.ShapeDtypeStruct(full, vdt))
-            out_specs.append(pl.BlockSpec(blk, out_map(len(vshape))))
+                full = (chs[0].local_chunks, chs[1].local_chunks,
+                        tiles[0].padded, tiles[1].padded) + vshape
+                spec = pl.BlockSpec(
+                    (None, None, tiles[0].tile, tiles[1].tile) + vshape,
+                    lambda qi, qj, ti, tj, vz=vz: (qi, qj, ti, tj) + vz)
+            out_shapes.append(jax.ShapeDtypeStruct(full,
+                                                   info.write.value_dtype))
+            out_specs.append(spec)
             out_keys.append((si, key))
-    return inputs, in_specs, loaders, out_shapes, out_specs, out_keys
+    return inputs, out_shapes, out_specs, out_keys
+
+
+# Lowered kernels (by module text) that already compiled for the chip.
+_PREFLIGHT_OK: set = set()
+
+
+def _preflight(call, args, names, device) -> None:
+    """Compile a span's kernel on its own for ``device`` so that a kernel
+    Mosaic refuses surfaces as a ``CompileError`` naming the span, at
+    ``omp.compile`` time."""
+    from jax.sharding import SingleDeviceSharding
+
+    from repro.core.api import CompileError
+
+    sharding = SingleDeviceSharding(device)
+    avals = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+             for a in args]
+    try:
+        lowered = jax.jit(call).lower(*avals)
+        text = lowered.as_text()
+        if text not in _PREFLIGHT_OK:
+            lowered.compile()
+            _PREFLIGHT_OK.add(text)
+    except Exception as e:
+        reason = " ".join(str(e).split())[:1200]
+        raise CompileError(
+            f"Lowering.PALLAS: span {'+'.join(names)!r} does not compile "
+            f"for {device.device_kind}: {type(e).__name__}: {reason}") from e
 
 
 def execute_span(stages: list[SpanStage], device_indices: tuple,
-                 interpret: bool) -> list[tuple[dict, dict]]:
+                 interpret: bool, device=None) -> list[tuple[dict, dict]]:
     """Run a span's loop bodies as ONE tiled pallas_call; returns the
     ``(carry, ys)`` pair of every stage (the ``_run_local_chunks``
-    contract), merges computed outside the kernel."""
+    contract), merges computed outside the kernel.  Compiled (not
+    interpreted) spans are first compiled alone for ``device``."""
     plan0 = stages[0].plan
     rank = plan0.rank
     chs = plan0.chunks_axes
     dt = _span_dtype([sp.plan for sp in stages])
     tiles = tuple(derive_axis_tiles(ch.chunk, dt) for ch in chs)
+    lanes = tuple(tl.tile for tl in tiles)
 
-    (inputs, in_specs, loaders,
-     out_shapes, out_specs, out_keys) = _collect_io(stages, rank, tiles)
+    inputs, out_shapes, out_specs, out_keys = _collect_io(stages, rank, tiles)
     if not out_keys:
         return [({}, {}) for _ in stages]
-
+    bodies = [_trace_body(sp.plan, sp.program) for sp in stages]
+    out_index = {k: oi for oi, k in enumerate(out_keys)}
     meta = jnp.stack([jnp.asarray(d, jnp.int32) for d in device_indices])
-    n_in = len(loaders)
+    n_in = len(inputs)
 
     def kernel(*refs):
         meta_ref = refs[0]
         in_refs = refs[1:1 + n_in]
         out_refs = refs[1 + n_in:]
-        if rank == 1:
-            q, ti = pl.program_id(0), pl.program_id(1)
-            d = meta_ref[0]
-            k0 = (q * chs[0].num_devices + d) * chs[0].chunk
-            bases = (k0 + ti * tiles[0].tile,)
-            k0s = (k0,)
-            lane_ks = (bases[0]
-                       + jax.lax.iota(jnp.int32, tiles[0].tile),)
-        else:
-            qi, qj = pl.program_id(0), pl.program_id(1)
-            ti, tj = pl.program_id(2), pl.program_id(3)
-            d_i, d_j = meta_ref[0], meta_ref[1]
-            k0_i = (qi * chs[0].num_devices + d_i) * chs[0].chunk
-            k0_j = (qj * chs[1].num_devices + d_j) * chs[1].chunk
-            bases = (k0_i + ti * tiles[0].tile,
-                     k0_j + tj * tiles[1].tile)
-            k0s = (k0_i, k0_j)
-            lane_ks = (bases[0] + jax.lax.iota(jnp.int32, tiles[0].tile),
-                       bases[1] + jax.lax.iota(jnp.int32, tiles[1].tile))
-
-        loaded = {}
-        for (kind, si, key), ref in zip(loaders, in_refs):
-            val = ref[...]
-            if kind == "win":
-                loaded[(si, key)] = val[0]
-            elif kind == "win2":
-                loaded[(si, key)] = val[0, :, 0]
-            elif kind == "scalar":
-                loaded[(si, key)] = val[0]
-            else:
-                loaded[(si, key)] = val
+        qs = [pl.program_id(a) for a in range(rank)]
+        ts = [pl.program_id(rank + a) for a in range(rank)]
+        # masked remainder lanes clamp to the last in-bounds iteration,
+        # exactly like the chunk-cyclic trip padding
+        ivals = []
+        for a, (ch, tl, loop) in enumerate(zip(chs, tiles, plan0.nest.axes)):
+            k0 = (qs[a] * ch.num_devices + meta_ref[a]) * ch.chunk \
+                + ts[a] * tl.tile
+            ks = k0 + (jax.lax.iota(jnp.int32, tl.tile) if rank == 1 else
+                       jax.lax.broadcasted_iota(jnp.int32, lanes, a))
+            kc = jnp.minimum(ks, max(0, loop.trip_count - 1))
+            ivals.append((loop.start + loop.step * kc, (True,) * rank))
+        loaded = {(inp.si, inp.key): inp.load(ref, ts)
+                  for inp, ref in zip(inputs, in_refs)}
 
         span_vals: dict[str, Any] = {}
-        for si, sp in enumerate(stages):
-            plan, prog = sp.plan, sp.program
-            loops = plan.nest.axes
-            # masked remainder lanes clamp to the last in-bounds
-            # iteration, exactly like the chunk-cyclic trip padding
-            ivecs = []
-            for ax, (loop, ks) in enumerate(zip(loops, lane_ks)):
-                kc = jnp.minimum(ks, max(0, loop.trip_count - 1))
-                ivecs.append(loop.start + loop.step * kc)
-            env_sub: dict[str, Any] = {}
-            for key in plan.context.env_keys:
+        for si, (sp, body) in enumerate(zip(stages, bodies)):
+            plan = sp.plan
+            srcs = {}
+            for key in body.env_keys:
                 dec = plan.vars[key]
-                info = plan.context.vars[key]
                 if dec.in_strategy in ("shard", "shard_halo"):
-                    ndim_sh = (getattr(dec, "shard_ndim", 1)
-                               if rank == 2 else 1)
-                    if key in sp.forwarded:
-                        offs = tuple(bases[a] + _halo_base(dec, a)
-                                     for a in range(rank))
-                        env_sub[key] = ShiftedWindow(
-                            span_vals[key], offs, info.shape, info.dtype)
-                    else:
-                        offs = tuple(k0s[a] + _halo_base(dec, a)
-                                     for a in range(ndim_sh))
-                        env_sub[key] = ShiftedWindow(
-                            loaded[(si, key)], offs,
-                            info.shape, info.dtype)
+                    r = dec.shard_ndim if rank == 2 else 1
+                    srcs[key] = _Src(
+                        "win", span_vals[key] if key in sp.forwarded
+                        else loaded[(si, key)],
+                        tuple(_halo_base(dec, a) for a in range(r)))
                 elif dec.in_strategy == "replicate":
-                    env_sub[key] = loaded[(si, key)]
+                    srcs[key] = _Src("val", loaded[(si, key)])
                 else:
-                    env_sub[key] = jnp.zeros(info.shape, info.dtype)
-            if rank == 1:
-                updates = jax.vmap(
-                    lambda i: prog.body(i, env_sub))(ivecs[0])
-            else:
-                updates = jax.vmap(lambda i: jax.vmap(
-                    lambda jv: prog.body(i, jv, env_sub))(ivecs[1])
-                )(ivecs[0])
-            for oi, (osi, key) in enumerate(out_keys):
-                if osi != si:
-                    continue
-                v = updates[key].value.astype(out_shapes[oi].dtype)
-                out_refs[oi][...] = (v[None] if rank == 1
-                                     else v[None, :, None])
-                if sp.plan.vars[key].out_strategy in ("identity",
-                                                      "partial"):
+                    srcs[key] = _Src("zeros", info=plan.context.vars[key])
+            keys_out = [k for (osi, k) in out_keys if osi == si]
+            got = _eval_body(body, plan, ivals, srcs, lanes, keys_out)
+            for key in keys_out:
+                oi = out_index[(si, key)]
+                v = _full(*got[key], lanes).astype(out_shapes[oi].dtype)
+                out_refs[oi][...] = v[None] if v.ndim == 1 else v
+                if plan.vars[key].out_strategy in ("identity", "partial"):
                     span_vals[key] = v
 
-    if rank == 1:
-        grid = (chs[0].local_chunks, tiles[0].n_tiles)
-    else:
-        grid = (chs[0].local_chunks, chs[1].local_chunks,
-                tiles[0].n_tiles, tiles[1].n_tiles)
-    outs = pl.pallas_call(
+    grid = tuple(ch.local_chunks for ch in chs) \
+        + tuple(tl.n_tiles for tl in tiles)
+    names = [sp.name for sp in stages]
+    params = {}
+    if not interpret and device.device_kind in _VMEM_BYTES:
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_BYTES[device.device_kind] * 3 // 4)
+    call = pl.pallas_call(
         kernel, grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + in_specs,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+        + [inp.spec for inp in inputs],
         out_specs=out_specs, out_shape=out_shapes,
-        interpret=interpret,
-    )(meta, *inputs)
+        interpret=interpret, name="omp_" + "_".join(names), **params)
+    args = [meta] + [inp.array for inp in inputs]
+    if not interpret:
+        _preflight(call, args, names, device)
+    outs = call(*args)
     if not isinstance(outs, (list, tuple)):
         outs = [outs]
 
@@ -466,9 +746,12 @@ def execute_span(stages: list[SpanStage], device_indices: tuple,
                 continue
             v = outs[oi]
             if rank == 1:
+                if not sp.plan.context.vars[key].write.value_shape:
+                    v = v[:, 0]                 # (n, 1, padded) rows
                 vals[key] = v[:, :tiles[0].chunk]
             else:
-                vals[key] = v[:, :tiles[0].chunk, :, :tiles[1].chunk]
+                vals[key] = jnp.moveaxis(v, 1, 2)[
+                    :, :tiles[0].chunk, :, :tiles[1].chunk]
         if rank == 1:
             results.append(merge_chunk_values(sp.plan, vals,
                                               device_indices[0]))
@@ -479,23 +762,25 @@ def execute_span(stages: list[SpanStage], device_indices: tuple,
 
 
 def run_local_chunks_pallas(plan, program, env_in, slab_stacks,
-                            device_index, *, interpret: bool):
+                            device_index, *, interpret: bool, device=None):
     """Drop-in for ``transform._run_local_chunks`` backed by one
     pallas_call over this device's slab."""
     sp = SpanStage(name=plan.name, plan=plan, program=program,
                    ext_windows=slab_stacks, env_repl=env_in,
                    forwarded=frozenset())
-    (carry, ys), = execute_span([sp], (device_index,), interpret)
+    (carry, ys), = execute_span([sp], (device_index,), interpret, device)
     return carry, ys
 
 
 def run_local_chunks_pallas2(plan, program, env_in, slab_stacks,
-                             device_indices, *, interpret: bool):
+                             device_indices, *, interpret: bool,
+                             device=None):
     """Rank-2 drop-in for ``transform._run_local_chunks2``."""
     sp = SpanStage(name=plan.name, plan=plan, program=program,
                    ext_windows=slab_stacks, env_repl=env_in,
                    forwarded=frozenset())
-    (carry, ys), = execute_span([sp], tuple(device_indices), interpret)
+    (carry, ys), = execute_span([sp], tuple(device_indices), interpret,
+                                device)
     return carry, ys
 
 

@@ -624,7 +624,8 @@ def _execute_region(dr: DistributedRegion, env: dict) -> dict:
                 written |= plx._written_keys(sp_plan)
             for sj, res in zip(span_of[si],
                                plx.execute_span(specs, (d,),
-                                                pallas_interp)):
+                                                pallas_interp,
+                                                mesh.devices.flat[0])):
                 span_results[sj] = res
         # hoisted exchanges: (consumer stage idx, key) -> read window,
         # issued right after the producing stage (the prefetch)
@@ -908,7 +909,8 @@ def _execute_region2(dr: DistributedRegion, env: dict) -> dict:
                 written |= plx._written_keys(sp_plan)
             for sj, res in zip(span_of[si],
                                plx.execute_span(specs, (d_i, d_j),
-                                                pallas_interp)):
+                                                pallas_interp,
+                                                mesh.devices.flat[0])):
                 span_results[sj] = res
 
         def issue_prefetch(after_idx):

@@ -473,7 +473,7 @@ def _execute_collective(dp: DistributedProgram, env: dict) -> dict:
         if dp.use_pallas:
             carry, ys = plx.run_local_chunks_pallas(
                 plan, program, env_repl, slab_stacks, d,
-                interpret=pallas_interp)
+                interpret=pallas_interp, device=mesh.devices.flat[0])
         else:
             carry, ys = _run_local_chunks(plan, program, env_repl,
                                           slab_stacks, d, dp.unroll_chunks)
@@ -726,7 +726,7 @@ def _execute_collective2(dp: DistributedProgram, env: dict) -> dict:
         if dp.use_pallas:
             carry, ys = plx.run_local_chunks_pallas2(
                 plan, program, env_repl, slab_stacks, (d_i, d_j),
-                interpret=pallas_interp)
+                interpret=pallas_interp, device=mesh.devices.flat[0])
         else:
             carry, ys = _run_local_chunks2(plan, program, env_repl,
                                            slab_stacks, (d_i, d_j),

@@ -1,7 +1,7 @@
 """Post-optimization HLO analysis: collective bytes + roofline terms.
 
 ``compiled.cost_analysis()`` reports FLOPs/bytes with every ``while``
-(scan) body counted ONCE (verified on jax 0.8.2), and collective traffic
+(scan) body counted ONCE, and collective traffic
 not at all.  This module parses the per-device SPMD HLO text:
 
 * splits it into computations,

@@ -11,7 +11,7 @@ import os
 
 import numpy as np
 
-from tests._hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 

@@ -4,9 +4,9 @@ differential pin that weighted outputs match unweighted bit-for-bit.
 A weighted schedule changes *who runs which chunk*, never *what is
 computed*: `make_chunk_plan(weights=...)` re-deals chunk ownership via
 `rebalance_chunks` and records the permutation in `ChunkPlan.slot_map`;
-staging/reassembly gather through it.  Element-wise and stencil outputs
-are therefore bit-identical to the cyclic deal; reductions regroup
-their per-device partial folds and match to float tolerance.
+staging/reassembly gather through it.  Outputs outside reductions are
+therefore bit-identical to the cyclic deal; reductions regroup their
+per-device partial folds and match to float tolerance.
 """
 import os
 
@@ -129,10 +129,19 @@ def test_degenerate_weight_values_rejected():
 
 def run_weighted_sweep() -> None:
     """Subprocess entry (8 virtual devices): weighted compiles of every
-    rank-1 and rank-2 family match the unweighted reference."""
+    rank-1 and rank-2 family match the unweighted compile bit-for-bit
+    and the shared-memory reference.
+
+    Against the reference, matmul families may differ by a few ulp: the
+    distributed program computes each chunk's dot at the chunk's shape,
+    and XLA accumulates it in another order than the reference's one
+    dot over the whole iteration space.  The unweighted compile differs
+    from the reference in exactly the same elements, so the weighted
+    deal itself moves no bit."""
     from tests.test_differential import FAMILIES, FAMILIES2, make_case, make_case2
 
     W8 = [2.0, 1.0, 1.0, 0.5, 1.0, 3.0, 1.0, 0.25]
+    MATMUL_ULP = 4
 
     def red_keys(prog):
         stages = getattr(prog, "stages", None)
@@ -144,18 +153,23 @@ def run_weighted_sweep() -> None:
 
     def check(prog, env, mesh, weights, tag):
         ref = prog(env)
+        unw = omp.compile(prog, mesh, lowering="collective")(env)
         out = omp.compile(prog, mesh, lowering="collective",
                           chunk_weights=weights)(env)
         reds = red_keys(prog)
         for k in ref:
+            got, want = np.asarray(out[k]), np.asarray(ref[k])
             if k in reds:
                 np.testing.assert_allclose(
-                    np.asarray(out[k]), np.asarray(ref[k]),
-                    rtol=1e-5, atol=1e-6, err_msg=f"{tag} key={k!r}")
+                    got, want, rtol=1e-5, atol=1e-6, err_msg=f"{tag} key={k!r}")
+                continue
+            np.testing.assert_array_equal(
+                got, np.asarray(unw[k]), err_msg=f"{tag} vs unweighted key={k!r}")
+            if "matmul" in tag:
+                np.testing.assert_array_max_ulp(got, want, maxulp=MATMUL_ULP)
             else:
-                np.testing.assert_array_equal(
-                    np.asarray(out[k]), np.asarray(ref[k]),
-                    err_msg=f"{tag} key={k!r}")
+                np.testing.assert_array_equal(got, want,
+                                              err_msg=f"{tag} key={k!r}")
 
     mesh = make_mesh((8,), ("data",))
     for fi, fam in enumerate(FAMILIES):
