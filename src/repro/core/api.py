@@ -45,8 +45,8 @@ Compilation is cached: a structural key (program signature, mesh
 shape/axes, Options, env shapes) lets repeated compiles — benchmark
 sweeps, the differential harness — skip re-planning entirely.  The
 cache is thread-safe for the concurrent compile service
-(:mod:`repro.serving.compile_service`): warm hits stay lock-free, the
-miss path inserts and evicts under a lock.  With
+(:mod:`repro.serving.compile_service`): warm hits never take the cache
+lock, the miss path inserts and evicts under it.  With
 :func:`enable_persistent_cache` (or ``$REPRO_AOT_CACHE_DIR``) compiled
 executables additionally persist across processes through the
 versioned AOT store (:mod:`repro.core.aot_store`): cold builds export
@@ -54,6 +54,15 @@ and save the XLA executable, fresh processes restore it instead of
 re-planning and re-compiling.  Stats via :func:`compile_cache_stats`
 (including disk hit/miss/bytes counters); ``benchmarks/run.py --json``
 records the cold/warm split in its ``compile_cache`` section.
+
+Each pass runs under the host span ``omp.pass.<name>`` and records its
+wall seconds on its :class:`PassRecord`; ``Compiled.run`` counts and
+times its entries into the executor (host span ``omp.executor``).
+Process-wide totals: :func:`repro.core.timing.stats` (``omp.timing_stats``).
+The generated program names its parts with ``jax.named_scope``
+(``omp.region.*``/``omp.block.*``, ``omp.entry``, ``omp.stage.*``,
+``omp.kernel.*``, ``omp.exchange.*``, ``omp.gather.*``, ``omp.combine*``,
+``omp.exit``), which the compiled HLO keeps in each op's metadata.
 """
 from __future__ import annotations
 
@@ -63,15 +72,18 @@ import itertools
 import math
 import os
 import threading
+import time
 from typing import Any, Mapping
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import aot_store as aot_store_mod
 from repro.core import pragma
 from repro.core import plan as plan_mod
+from repro.core import timing
 from repro.core.context import _aval_of
 from repro.core.loop import LoopNotCanonical
 
@@ -369,13 +381,18 @@ PASS_NAMES = ("analyze", "schedule", "plan", "plan_comm", "schedule_comm",
 
 @dataclasses.dataclass(frozen=True)
 class PassRecord:
-    """One pipeline stage: what went in, what came out."""
+    """One pipeline stage: what went in, what came out, how long it took."""
 
     name: str
     input: str
     """Short description of the artifact(s) the pass consumed."""
     output: Any
     """The artifact the pass produced (consumed by the next pass)."""
+    seconds: float = 0.0
+    """Wall seconds of the pass (:mod:`repro.core.timing`).  Every
+    record of a cache hit but ``lower`` is the build's it reuses, with
+    that build's seconds; ``lower`` runs again on every hit and times
+    itself."""
 
     def describe(self) -> str:
         out = self.output
@@ -383,7 +400,8 @@ class PassRecord:
             kind = f"{len(out)} artifact(s)"
         else:
             kind = type(out).__name__
-        return f"{self.name}: {self.input} -> {kind}"
+        return (f"{self.name}: {self.input} -> {kind} "
+                f"({1e3 * self.seconds:.1f} ms)")
 
 
 # ---------------------------------------------------------------------------
@@ -680,13 +698,21 @@ def _lowering_str(options: Options) -> str:
 
 
 def _build_artifacts(program, env_like, num, axis, options) -> _Artifacts:
+    """Run the passes before ``lower``, each record with its seconds."""
     env_shapes = {k: _aval_of(v) for k, v in env_like.items()}
     if isinstance(program, pragma.ParallelRegion):
         if options.lowering in (Lowering.FUSED, Lowering.PALLAS):
-            return _build_region_fused(program, env_shapes, num, axis,
-                                       options)
-        return _build_region_staged(program, env_shapes, num, axis, options)
-    return _build_block(program, env_shapes, num, axis, options)
+            build = _build_region_fused
+        else:
+            build = _build_region_staged
+    else:
+        build = _build_block
+    with timing.PassClock() as clock:
+        art = build(program, env_shapes, num, axis, options)
+    art.passes = tuple(
+        dataclasses.replace(pr, seconds=clock.seconds.get(pr.name, 0.0))
+        for pr in art.passes)
+    return art
 
 
 def _pallas_pass(options: Options, kernel_plan) -> tuple:
@@ -820,7 +846,8 @@ def _build_region_staged(region, env_shapes, num, axis,
     for stage in region.stages:
         if isinstance(stage, pragma.SerialStage):
             try:
-                out_sh = jax.eval_shape(stage.fn, shapes)
+                with timing.timed_pass("analyze"):
+                    out_sh = jax.eval_shape(stage.fn, shapes)
             except Exception as e:  # host-side glue: shapes unknowable
                 deferred = (f"serial stage {stage.name!r} is not "
                             f"shape-traceable ({type(e).__name__}); "
@@ -969,7 +996,9 @@ class Compiled:
       :class:`~repro.core.comm.BoundaryComm` list (fused regions),
     * ``.report()``     — the rendered "generated MPI code" view,
     * ``.cost_summary()`` — modeled communication totals as a dict,
-    * ``.cache_hit``    — whether the last build came from the cache.
+    * ``.cache_hit``    — whether the last build came from the cache,
+    * ``.executor_runs`` / ``.executor_seconds`` — entries into the
+      executor from :meth:`run`, and their wall seconds.
 
     The pipeline needs environment *shapes*; compile with ``env_like=``
     to run it eagerly, otherwise it runs (through the compilation
@@ -983,6 +1012,12 @@ class Compiled:
     axis: Any
     num_devices: Any
     cache_hit: bool | None = None
+    executor_runs: int = dataclasses.field(default=0, compare=False)
+    """Entries into the executor from :meth:`run`: under ``jax.jit``
+    one per trace, and one per bare (eager) call, each of which
+    compiles again."""
+    executor_seconds: float = dataclasses.field(default=0.0, compare=False)
+    """Wall seconds of those entries (host span ``omp.executor``)."""
     _exe: Any = dataclasses.field(default=None, repr=False)
     _passes: tuple | None = dataclasses.field(default=None, repr=False)
     _env_sig: tuple | None = dataclasses.field(default=None, repr=False)
@@ -1012,7 +1047,15 @@ class Compiled:
         if out is None:
             if self._exe is None:
                 self._ensure(env, allow_restore=False)
-            out = self._exe(env)
+            t0 = time.perf_counter()
+            try:
+                with TraceAnnotation("omp.executor"):
+                    out = self._exe(env)
+            finally:
+                seconds = time.perf_counter() - t0
+                self.executor_runs += 1
+                self.executor_seconds += seconds
+                timing.add_executor(seconds)
         if _fault_hook is not None:
             out = _fault_hook("run_exit", out)
         return out
@@ -1071,17 +1114,21 @@ class Compiled:
             self._runner = runner
 
     def _bind(self, art: _Artifacts, sig: tuple) -> None:
-        exe = _make_executor(self.program, self.mesh, self.axis,
-                             self.options, art.exe_plan)
+        with timing.PassClock() as clock, timing.timed_pass("lower"):
+            exe = _make_executor(self.program, self.mesh, self.axis,
+                                 self.options, art.exe_plan)
+            self._passes = art.passes
+            self._exe = exe
+            self._env_sig = sig
+            self._runner = None
+            if self.options.lowering is Lowering.PALLAS \
+                    and _on_tpu(self.mesh):
+                # tracing compiles every span for the chip: a span Mosaic
+                # refuses raises CompileError here, not at the first call
+                jax.eval_shape(lambda env: dict(exe(env)), _sig_avals(sig))
         self._passes = art.passes + (PassRecord(
-            "lower", input="planned artifacts + mesh", output=exe),)
-        self._exe = exe
-        self._env_sig = sig
-        self._runner = None
-        if self.options.lowering is Lowering.PALLAS and _on_tpu(self.mesh):
-            # tracing compiles every span for the chip: a span Mosaic
-            # refuses raises CompileError here, not at the first call
-            jax.eval_shape(lambda env: dict(exe(env)), _sig_avals(sig))
+            "lower", input="planned artifacts + mesh", output=exe,
+            seconds=clock.seconds["lower"]),)
 
     def _disk_key(self, sig: tuple) -> str:
         return aot_store_mod.fingerprint(

@@ -72,6 +72,7 @@ from repro.core.nest import (  # noqa: F401 (re-exports)
     window_extent,
     window_rows,
 )
+from repro.core.timing import timed_pass
 
 
 # ---------------------------------------------------------------------------
@@ -328,6 +329,7 @@ def halo_cost2(layout: SlabLayout2, aval, deltas) -> CommCost:
     )
 
 
+@timed_pass("plan_comm")
 def plan_boundary2(
     *,
     stage: str,
@@ -429,6 +431,7 @@ def plan_boundary2(
     )
 
 
+@timed_pass("plan_comm")
 def plan_boundary(
     *,
     stage: str,

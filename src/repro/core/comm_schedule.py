@@ -51,6 +51,7 @@ import jax.numpy as jnp
 
 from repro.core import comm as comm_mod
 from repro.core import reduction as red_mod
+from repro.core.timing import timed_pass
 
 SCHEDULE_MODES = ("aggregate", "inline")
 
@@ -215,6 +216,7 @@ def _stage_combines(plan, rank: int) -> list[tuple[str, str, str]]:
     return out
 
 
+@timed_pass("schedule_comm")
 def build_comm_schedule(rp, *, mode: str = "aggregate") -> CommSchedule:
     """Schedule a planned region's communication: the **schedule_comm**
     pass.  Walks ``rp.stages`` in order, pairing every ``halo`` feed

@@ -50,6 +50,7 @@ from repro.core import context as ctx_mod
 from repro.core import nest as nest_mod
 from repro.core import reduction as red_mod
 from repro.core.nest import AxisTiles, NestAffine, derive_axis_tiles
+from repro.core.timing import timed_pass
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -219,6 +220,7 @@ def _span_meta(plans, names, indices) -> KernelSpan:
                       forwarded=tuple(fwd), n_outputs=n_out)
 
 
+@timed_pass("pallas")
 def plan_block_kernel(plan, name: str | None = None) -> KernelPlan:
     """KernelPlan of a single ParallelFor block (one span)."""
     if plan.nest.total_trip == 0 or not _written_keys(plan):
@@ -229,6 +231,7 @@ def plan_block_kernel(plan, name: str | None = None) -> KernelPlan:
                       spans=(span,), n_loop_stages=1)
 
 
+@timed_pass("pallas")
 def plan_region_kernels(rp) -> KernelPlan:
     """KernelPlan of a fused region: one span per exchange-free chain."""
     spans = []
@@ -734,7 +737,8 @@ def execute_span(stages: list[SpanStage], device_indices: tuple,
     args = [meta] + [inp.array for inp in inputs]
     if not interpret:
         _preflight(call, args, names, device)
-    outs = call(*args)
+    with jax.named_scope("omp.kernel." + "_".join(names)):
+        outs = call(*args)
     if not isinstance(outs, (list, tuple)):
         outs = [outs]
 

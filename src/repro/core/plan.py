@@ -50,6 +50,7 @@ from repro.core import schedule as schedule_mod
 from repro.core.context import ReadKind, VarClass, WriteKind
 from repro.core.loop import LoopInfo, LoopNotCanonical, analyze_loop
 from repro.core.nest import LoopNest, NestAffine
+from repro.core.timing import timed_pass
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,6 +144,7 @@ class DistPlan:
         return [k for k, v in self.vars.items() if v.in_strategy == "replicate"]
 
 
+@timed_pass("analyze")
 def analyze_program(
     program: pragma.ParallelFor,
     env: Mapping[str, Any],
@@ -158,6 +160,7 @@ def analyze_program(
     return nest, ctx
 
 
+@timed_pass("schedule")
 def plan_schedule(
     program: pragma.ParallelFor,
     nest: LoopNest,
@@ -258,6 +261,7 @@ def make_plan(
         shard_inputs=shard_inputs)
 
 
+@timed_pass("plan")
 def decide_strategies(
     program: pragma.ParallelFor,
     nest: LoopNest,

@@ -72,6 +72,7 @@ from repro.core.comm import (  # noqa: F401 (re-export)
 from repro.core.loop import LoopNotCanonical
 from repro.core.plan import DistPlan, make_plan
 from repro.core.tensor_plan import slab_spec
+from repro.core.timing import timed_pass
 
 REPLICATED = "repl"
 
@@ -155,6 +156,7 @@ def _boundary_replicated(stage_name, key, st, aval, comm, chunks=None):
         mode=comm)
 
 
+@timed_pass("plan")
 def plan_region(
     region: pragma.ParallelRegion,
     env: Mapping[str, Any],
@@ -454,17 +456,19 @@ class DistributedRegion:
         from repro.core import comm_schedule as cs_mod
 
         env = {k: jnp.asarray(v) for k, v in env.items()}
-        if self.lowering != "collective" or not self.fuse:
-            return self._run_staged(env)
-        if self.plan is None:
-            self.plan = plan_region(
-                self.region, env, tf.mesh_axis_sizes(self.mesh, self.axis),
-                axis=self.axis, comm=self.comm,
-                schedule=self.schedule_override)
-        if self.plan.comm_sched is None:
-            self.plan.comm_sched = cs_mod.build_comm_schedule(
-                self.plan, mode=self.comm_schedule)
-        return _execute_region(self, env)
+        with jax.named_scope(f"omp.region.{self.region.name}"):
+            if self.lowering != "collective" or not self.fuse:
+                return self._run_staged(env)
+            if self.plan is None:
+                self.plan = plan_region(
+                    self.region, env,
+                    tf.mesh_axis_sizes(self.mesh, self.axis),
+                    axis=self.axis, comm=self.comm,
+                    schedule=self.schedule_override)
+            if self.plan.comm_sched is None:
+                self.plan.comm_sched = cs_mod.build_comm_schedule(
+                    self.plan, mode=self.comm_schedule)
+            return _execute_region(self, env)
 
     def _run_staged(self, env: dict) -> dict:
         """Paper-faithful baseline: each loop transformed in isolation
@@ -476,7 +480,8 @@ class DistributedRegion:
             else None
         for stage in self.region.stages:
             if isinstance(stage, pragma.SerialStage):
-                out = stage(out)
+                with jax.named_scope(f"omp.stage.{stage.name}"):
+                    out = stage(out)
                 continue
             plan = None
             if plans is not None:
@@ -560,6 +565,12 @@ def region_to_mpi(
 # ---------------------------------------------------------------------------
 
 
+def _exchange_scope(keys) -> str:
+    """Device scope of one boundary exchange (a packed group names all
+    of its buffers)."""
+    return "omp.exchange." + "+".join(keys)
+
+
 def _execute_region(dr: DistributedRegion, env: dict) -> dict:
     from repro.core import comm_schedule as cs_mod
 
@@ -613,8 +624,9 @@ def _execute_region(dr: DistributedRegion, env: dict) -> dict:
                             else:               # "slice"
                                 halo = (dec.halo if dec.halo is not None
                                         else (0, 0))
-                                ext[key] = nest_mod.local_slabs(
-                                    st[key][1], sp_plan.chunks, halo, d)
+                                with jax.named_scope("omp.entry"):
+                                    ext[key] = nest_mod.local_slabs(
+                                        st[key][1], sp_plan.chunks, halo, d)
                         elif dec.in_strategy == "replicate":
                             repl[key] = st[key][1]
                 specs.append(plx.SpanStage(
@@ -640,9 +652,11 @@ def _execute_region(dr: DistributedRegion, env: dict) -> dict:
                         stacks=stacks, chunks=ev.chunks, shifts=ev.shifts,
                         prior=sprior, bases=(sbase,), covers=(scover,),
                         dtype=sdtype))
-                wins = cs_mod.aggregated_halo_exchange(
-                    items, axis=axis, num_devices=grp.events[0].num_devices[0],
-                    device_index=d)
+                with jax.named_scope(_exchange_scope(grp.keys)):
+                    wins = cs_mod.aggregated_halo_exchange(
+                        items, axis=axis,
+                        num_devices=grp.events[0].num_devices[0],
+                        device_index=d)
                 for ev, win in zip(grp.events, wins):
                     prefetched[(ev.consumer_idx, ev.key)] = win
 
@@ -651,13 +665,14 @@ def _execute_region(dr: DistributedRegion, env: dict) -> dict:
             if tag == "repl":
                 return st[key][1]
             _, stacks, base, cover, prior, dtype = st[key]
-            g = jax.lax.all_gather(stacks, axis, axis=1, tiled=False)
-            flat = g.reshape((-1,) + g.shape[3:])[:cover].astype(dtype)
-            if prior is None:
-                full = flat
-            else:
-                full = jax.lax.dynamic_update_slice_in_dim(
-                    prior, flat, base, 0)
+            with jax.named_scope(f"omp.gather.{key}"):
+                g = jax.lax.all_gather(stacks, axis, axis=1, tiled=False)
+                flat = g.reshape((-1,) + g.shape[3:])[:cover].astype(dtype)
+                if prior is None:
+                    full = flat
+                else:
+                    full = jax.lax.dynamic_update_slice_in_dim(
+                        prior, flat, base, 0)
             st[key] = ("repl", full)
             return full
 
@@ -667,7 +682,8 @@ def _execute_region(dr: DistributedRegion, env: dict) -> dict:
 
             if se.kind == "serial":
                 env_full = {k: e[1] for k, e in st.items() if e[0] == "repl"}
-                upd = se.stage.fn(env_full)
+                with jax.named_scope(f"omp.stage.{se.name}"):
+                    upd = se.stage.fn(env_full)
                 for k, v in upd.items():
                     st[k] = ("repl", jnp.asarray(v))
                 continue
@@ -705,29 +721,32 @@ def _execute_region(dr: DistributedRegion, env: dict) -> dict:
                             # point-to-point boundary exchange (§3.1.4)
                             _, stacks, sbase, scover, sprior, sdtype = st[key]
                             h = dec.halo if dec.halo is not None else (0, 0)
-                            slab_stacks[key] = comm_mod.halo_exchange(
-                                stacks, axis=axis,
-                                num_devices=plan.chunks.num_devices,
-                                device_index=d, chunk=plan.chunks.chunk,
-                                delta_min=h[0] - sbase,
-                                delta_max=h[1] - sbase,
-                                prior=sprior, base=sbase, cover=scover,
-                                dtype=sdtype)
+                            with jax.named_scope(_exchange_scope((key,))):
+                                slab_stacks[key] = comm_mod.halo_exchange(
+                                    stacks, axis=axis,
+                                    num_devices=plan.chunks.num_devices,
+                                    device_index=d, chunk=plan.chunks.chunk,
+                                    delta_min=h[0] - sbase,
+                                    delta_max=h[1] - sbase,
+                                    prior=sprior, base=sbase, cover=scover,
+                                    dtype=sdtype)
                     else:
                         halo = dec.halo if dec.halo is not None else (0, 0)
-                        slab_stacks[key] = nest_mod.local_slabs(
-                            st[key][1], plan.chunks, halo, d)
+                        with jax.named_scope("omp.entry"):
+                            slab_stacks[key] = nest_mod.local_slabs(
+                                st[key][1], plan.chunks, halo, d)
                 elif dec.in_strategy == "replicate":
                     env_in[key] = st[key][1]
 
-            if not dr.use_pallas:
-                carry, ys = tf._run_local_chunks(
-                    plan, se.stage, env_in, slab_stacks, d,
-                    dr.unroll_chunks)
-            else:
-                if si not in span_results:
-                    run_span(si, env_in, slab_stacks)
-                carry, ys = span_results.pop(si)
+            with jax.named_scope(f"omp.stage.{se.name}"):
+                if not dr.use_pallas:
+                    carry, ys = tf._run_local_chunks(
+                        plan, se.stage, env_in, slab_stacks, d,
+                        dr.unroll_chunks)
+                else:
+                    if si not in span_results:
+                        run_span(si, env_in, slab_stacks)
+                    carry, ys = span_results.pop(si)
 
             # Cross-device combines of this stage's merges: issued
             # per-key inline, or deferred into fused flat collectives
@@ -753,59 +772,66 @@ def _execute_region(dr: DistributedRegion, env: dict) -> dict:
                         pending[(key, "mask")] = \
                             ("psum", mask.astype(jnp.int32))
                         continue
-                    summed = jax.lax.psum(buf, axis)
-                    m = jax.lax.psum(mask.astype(jnp.int32), axis)
-                    prior = st[key][1]
-                    vmask = (m > 0).reshape((-1,) + (1,) * (summed.ndim - 1))
-                    st[key] = ("repl", jnp.where(
-                        vmask, summed.astype(prior.dtype), prior))
+                    with jax.named_scope(f"omp.combine.{key}"):
+                        summed = jax.lax.psum(buf, axis)
+                        m = jax.lax.psum(mask.astype(jnp.int32), axis)
+                        prior = st[key][1]
+                        vmask = (m > 0).reshape(
+                            (-1,) + (1,) * (summed.ndim - 1))
+                        st[key] = ("repl", jnp.where(
+                            vmask, summed.astype(prior.dtype), prior))
                 elif dec.out_strategy == "put":
                     j_star = (t - 1) // plan.chunks.chunk
                     owner = j_star % plan.chunks.num_devices
-                    val = jnp.where(d == owner, carry[key],
-                                    jnp.zeros_like(carry[key]))
-                    if aggregate:
-                        pending[(key, "put")] = ("psum", val)
-                        continue
-                    st[key] = ("repl", jax.lax.psum(val, axis))
+                    with jax.named_scope(f"omp.combine.{key}"):
+                        val = jnp.where(d == owner, carry[key],
+                                        jnp.zeros_like(carry[key]))
+                        if aggregate:
+                            pending[(key, "put")] = ("psum", val)
+                            continue
+                        st[key] = ("repl", jax.lax.psum(val, axis))
                 elif dec.out_strategy == "reduce":
                     rop = red_mod.get_reduction(dec.reduction_op)
                     if aggregate and rop.collective in ("psum", "pmax",
                                                         "pmin"):
                         pending[(key, "red")] = (rop.collective, carry[key])
                         continue
-                    val = red_mod.cross_device_combine(rop, carry[key], axis)
-                    if key in st:
-                        val = rop.pairwise(st[key][1], val)
+                    with jax.named_scope(f"omp.combine.{key}"):
+                        val = red_mod.cross_device_combine(
+                            rop, carry[key], axis)
+                        if key in st:
+                            val = rop.pairwise(st[key][1], val)
                     st[key] = ("repl", val)
 
             if pending:
-                combined = cs_mod.fused_collectives(pending, axis)
-                for key, dec in plan.vars.items():
-                    if dec.out_strategy == "scatter":
-                        summed = combined[(key, "buf")]
-                        m = combined[(key, "mask")]
-                        prior = st[key][1]
-                        vmask = (m > 0).reshape(
-                            (-1,) + (1,) * (summed.ndim - 1))
-                        st[key] = ("repl", jnp.where(
-                            vmask, summed.astype(prior.dtype), prior))
-                    elif dec.out_strategy == "put":
-                        st[key] = ("repl", combined[(key, "put")])
-                    elif dec.out_strategy == "reduce" \
-                            and (key, "red") in combined:
-                        rop = red_mod.get_reduction(dec.reduction_op)
-                        val = combined[(key, "red")]
-                        if key in st:
-                            val = rop.pairwise(st[key][1], val)
-                        st[key] = ("repl", val)
+                with jax.named_scope("omp.combine"):
+                    combined = cs_mod.fused_collectives(pending, axis)
+                    for key, dec in plan.vars.items():
+                        if dec.out_strategy == "scatter":
+                            summed = combined[(key, "buf")]
+                            m = combined[(key, "mask")]
+                            prior = st[key][1]
+                            vmask = (m > 0).reshape(
+                                (-1,) + (1,) * (summed.ndim - 1))
+                            st[key] = ("repl", jnp.where(
+                                vmask, summed.astype(prior.dtype), prior))
+                        elif dec.out_strategy == "put":
+                            st[key] = ("repl", combined[(key, "put")])
+                        elif dec.out_strategy == "reduce" \
+                                and (key, "red") in combined:
+                            rop = red_mod.get_reduction(dec.reduction_op)
+                            val = combined[(key, "red")]
+                            if key in st:
+                                val = rop.pairwise(st[key][1], val)
+                            st[key] = ("repl", val)
 
             if aggregate:
                 issue_prefetch(si)
 
-        outs_repl = {k: st[k][1] for k in repl_out}
-        outs_slab = {k: st[k][1][:, None] for k in slab_out}
-        outs_prior = {k: st[k][4] for k in prior_out}
+        with jax.named_scope("omp.exit"):
+            outs_repl = {k: st[k][1] for k in repl_out}
+            outs_slab = {k: st[k][1][:, None] for k in slab_out}
+            outs_prior = {k: st[k][4] for k in prior_out}
         return outs_repl, outs_slab, outs_prior
 
     in_specs = ({k: P() for k in env},)
@@ -825,15 +851,16 @@ def _execute_region(dr: DistributedRegion, env: dict) -> dict:
     result = dict(env)
     for key in repl_out:
         result[key] = outs_repl[key]
-    for key, lay in slab_out.items():
-        g = outs_slab[key]                       # (n_loc, P, c, *rest)
-        flat = g.reshape((-1,) + g.shape[3:])[:lay.cover]
-        flat = flat.astype(env_dtypes.get(key, flat.dtype))
-        if lay.has_prior:
-            result[key] = jax.lax.dynamic_update_slice_in_dim(
-                outs_prior[key], flat, lay.base, 0)
-        else:
-            result[key] = flat
+    with jax.named_scope("omp.exit"):
+        for key, lay in slab_out.items():
+            g = outs_slab[key]                   # (n_loc, P, c, *rest)
+            flat = g.reshape((-1,) + g.shape[3:])[:lay.cover]
+            flat = flat.astype(env_dtypes.get(key, flat.dtype))
+            if lay.has_prior:
+                result[key] = jax.lax.dynamic_update_slice_in_dim(
+                    outs_prior[key], flat, lay.base, 0)
+            else:
+                result[key] = flat
     return result
 
 
@@ -893,13 +920,14 @@ def _execute_region2(dr: DistributedRegion, env: dict) -> dict:
                                          if dec.halo_axes is not None
                                          else ((0, 0), (0, 0)))
                                 x = st[key][1]
-                                if dec.shard_ndim == 2:
-                                    ext[key] = nest_mod.local_slabs2(
-                                        x, (sch_i, sch_j), halos,
-                                        (d_i, d_j))
-                                else:
-                                    ext[key] = nest_mod.local_slabs(
-                                        x, sch_i, halos[0], d_i)
+                                with jax.named_scope("omp.entry"):
+                                    if dec.shard_ndim == 2:
+                                        ext[key] = nest_mod.local_slabs2(
+                                            x, (sch_i, sch_j), halos,
+                                            (d_i, d_j))
+                                    else:
+                                        ext[key] = nest_mod.local_slabs(
+                                            x, sch_i, halos[0], d_i)
                         elif dec.in_strategy == "replicate":
                             repl[key] = st[key][1]
                 specs.append(plx.SpanStage(
@@ -922,10 +950,11 @@ def _execute_region2(dr: DistributedRegion, env: dict) -> dict:
                         stacks=stacks, chunks=ev.chunks, shifts=ev.shifts,
                         prior=sprior, bases=bases, covers=covers,
                         dtype=sdtype))
-                wins = cs_mod.aggregated_halo_exchange2(
-                    items, axes=(ax_i, ax_j),
-                    num_devices=grp.events[0].num_devices,
-                    device_indices=(d_i, d_j))
+                with jax.named_scope(_exchange_scope(grp.keys)):
+                    wins = cs_mod.aggregated_halo_exchange2(
+                        items, axes=(ax_i, ax_j),
+                        num_devices=grp.events[0].num_devices,
+                        device_indices=(d_i, d_j))
                 for ev, win in zip(grp.events, wins):
                     prefetched[(ev.consumer_idx, ev.key)] = win
 
@@ -934,17 +963,18 @@ def _execute_region2(dr: DistributedRegion, env: dict) -> dict:
             if tag == "repl":
                 return st[key][1]
             _, stacks, bases, covers, prior, dtype = st[key]
-            g = jax.lax.all_gather(stacks, ax_i, axis=1, tiled=False)
-            g = jax.lax.all_gather(g, ax_j, axis=4, tiled=False)
-            flat = g.reshape(
-                (g.shape[0] * g.shape[1] * g.shape[2],
-                 g.shape[3] * g.shape[4] * g.shape[5]) + g.shape[6:])
-            flat = flat[:covers[0], :covers[1]].astype(dtype)
-            if prior is None:
-                full = flat
-            else:
-                full = jax.lax.dynamic_update_slice(
-                    prior, flat, bases + (0,) * (flat.ndim - 2))
+            with jax.named_scope(f"omp.gather.{key}"):
+                g = jax.lax.all_gather(stacks, ax_i, axis=1, tiled=False)
+                g = jax.lax.all_gather(g, ax_j, axis=4, tiled=False)
+                flat = g.reshape(
+                    (g.shape[0] * g.shape[1] * g.shape[2],
+                     g.shape[3] * g.shape[4] * g.shape[5]) + g.shape[6:])
+                flat = flat[:covers[0], :covers[1]].astype(dtype)
+                if prior is None:
+                    full = flat
+                else:
+                    full = jax.lax.dynamic_update_slice(
+                        prior, flat, bases + (0,) * (flat.ndim - 2))
             st[key] = ("repl", full)
             return full
 
@@ -954,7 +984,8 @@ def _execute_region2(dr: DistributedRegion, env: dict) -> dict:
 
             if se.kind == "serial":
                 env_full = {k: e[1] for k, e in st.items() if e[0] == "repl"}
-                upd = se.stage.fn(env_full)
+                with jax.named_scope(f"omp.stage.{se.name}"):
+                    upd = se.stage.fn(env_full)
                 for k, v in upd.items():
                     st[k] = ("repl", jnp.asarray(v))
                 continue
@@ -989,37 +1020,41 @@ def _execute_region2(dr: DistributedRegion, env: dict) -> dict:
                             continue
                         _, stacks, bases, covers, prior, dtype = st[key]
                         halos = dec.halo_axes
-                        slab_stacks[key] = comm_mod.halo_exchange2(
-                            stacks, axes=(ax_i, ax_j),
-                            num_devices=(ch_i.num_devices, ch_j.num_devices),
-                            device_indices=(d_i, d_j),
-                            chunks=(ch_i.chunk, ch_j.chunk),
-                            deltas=tuple(
-                                (h[0] - b, h[1] - b)
-                                for h, b in zip(halos, bases)),
-                            prior=prior, bases=bases, covers=covers,
-                            dtype=dtype)
+                        with jax.named_scope(_exchange_scope((key,))):
+                            slab_stacks[key] = comm_mod.halo_exchange2(
+                                stacks, axes=(ax_i, ax_j),
+                                num_devices=(ch_i.num_devices,
+                                             ch_j.num_devices),
+                                device_indices=(d_i, d_j),
+                                chunks=(ch_i.chunk, ch_j.chunk),
+                                deltas=tuple(
+                                    (h[0] - b, h[1] - b)
+                                    for h, b in zip(halos, bases)),
+                                prior=prior, bases=bases, covers=covers,
+                                dtype=dtype)
                     else:
                         halos = (dec.halo_axes if dec.halo_axes is not None
                                  else ((0, 0), (0, 0)))
                         x = st[key][1]
-                        if dec.shard_ndim == 2:
-                            slab_stacks[key] = nest_mod.local_slabs2(
-                                x, (ch_i, ch_j), halos, (d_i, d_j))
-                        else:
-                            slab_stacks[key] = nest_mod.local_slabs(
-                                x, ch_i, halos[0], d_i)
+                        with jax.named_scope("omp.entry"):
+                            if dec.shard_ndim == 2:
+                                slab_stacks[key] = nest_mod.local_slabs2(
+                                    x, (ch_i, ch_j), halos, (d_i, d_j))
+                            else:
+                                slab_stacks[key] = nest_mod.local_slabs(
+                                    x, ch_i, halos[0], d_i)
                 elif dec.in_strategy == "replicate":
                     env_in[key] = st[key][1]
 
-            if not dr.use_pallas:
-                carry, ys = tf._run_local_chunks2(
-                    plan, se.stage, env_in, slab_stacks, (d_i, d_j),
-                    dr.unroll_chunks)
-            else:
-                if si not in span_results:
-                    run_span(si, env_in, slab_stacks)
-                carry, ys = span_results.pop(si)
+            with jax.named_scope(f"omp.stage.{se.name}"):
+                if not dr.use_pallas:
+                    carry, ys = tf._run_local_chunks2(
+                        plan, se.stage, env_in, slab_stacks, (d_i, d_j),
+                        dr.unroll_chunks)
+                else:
+                    if si not in span_results:
+                        run_span(si, env_in, slab_stacks)
+                    carry, ys = span_results.pop(si)
 
             reduce_items: dict[str, tuple] = {}
             for key, dec in plan.vars.items():
@@ -1042,27 +1077,31 @@ def _execute_region2(dr: DistributedRegion, env: dict) -> dict:
                     if aggregate:
                         reduce_items[key] = (rop, carry[key])
                         continue
-                    val = red_mod.cross_device_combine(
-                        rop, carry[key], (ax_i, ax_j))
-                    if key in st:
-                        val = rop.pairwise(st[key][1], val)
+                    with jax.named_scope(f"omp.combine.{key}"):
+                        val = red_mod.cross_device_combine(
+                            rop, carry[key], (ax_i, ax_j))
+                        if key in st:
+                            val = rop.pairwise(st[key][1], val)
                     st[key] = ("repl", val)
 
             if reduce_items:
-                combined = cs_mod.fused_cross_device_combine(
-                    reduce_items, (ax_i, ax_j))
-                for key, val in combined.items():
-                    rop = reduce_items[key][0]
-                    if key in st:
-                        val = rop.pairwise(st[key][1], val)
-                    st[key] = ("repl", val)
+                with jax.named_scope("omp.combine"):
+                    combined = cs_mod.fused_cross_device_combine(
+                        reduce_items, (ax_i, ax_j))
+                    for key, val in combined.items():
+                        rop = reduce_items[key][0]
+                        if key in st:
+                            val = rop.pairwise(st[key][1], val)
+                        st[key] = ("repl", val)
 
             if aggregate:
                 issue_prefetch(si)
 
-        outs_repl = {k: st[k][1] for k in repl_out}
-        outs_slab = {k: st[k][1][:, None, :, :, None] for k in slab_out}
-        outs_prior = {k: st[k][4] for k in prior_out}
+        with jax.named_scope("omp.exit"):
+            outs_repl = {k: st[k][1] for k in repl_out}
+            outs_slab = {k: st[k][1][:, None, :, :, None]
+                         for k in slab_out}
+            outs_prior = {k: st[k][4] for k in prior_out}
         return outs_repl, outs_slab, outs_prior
 
     in_specs = ({k: P() for k in env},)
@@ -1082,16 +1121,18 @@ def _execute_region2(dr: DistributedRegion, env: dict) -> dict:
     result = dict(env)
     for key in repl_out:
         result[key] = outs_repl[key]
-    for key, lay in slab_out.items():
-        g = outs_slab[key]               # (n_i, P_i, c_i, n_j, P_j, c_j, *)
-        flat = g.reshape(
-            (g.shape[0] * g.shape[1] * g.shape[2],
-             g.shape[3] * g.shape[4] * g.shape[5]) + g.shape[6:])
-        flat = flat[:lay.covers[0], :lay.covers[1]]
-        flat = flat.astype(env_dtypes.get(key, flat.dtype))
-        if lay.has_prior:
-            result[key] = jax.lax.dynamic_update_slice(
-                outs_prior[key], flat, lay.bases + (0,) * (flat.ndim - 2))
-        else:
-            result[key] = flat
+    with jax.named_scope("omp.exit"):
+        for key, lay in slab_out.items():
+            g = outs_slab[key]           # (n_i, P_i, c_i, n_j, P_j, c_j, *)
+            flat = g.reshape(
+                (g.shape[0] * g.shape[1] * g.shape[2],
+                 g.shape[3] * g.shape[4] * g.shape[5]) + g.shape[6:])
+            flat = flat[:lay.covers[0], :lay.covers[1]]
+            flat = flat.astype(env_dtypes.get(key, flat.dtype))
+            if lay.has_prior:
+                result[key] = jax.lax.dynamic_update_slice(
+                    outs_prior[key], flat,
+                    lay.bases + (0,) * (flat.ndim - 2))
+            else:
+                result[key] = flat
     return result

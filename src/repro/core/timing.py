@@ -1,0 +1,107 @@
+"""Wall seconds of the compiler's passes and of the generated program's
+executor, with their host spans.
+
+Every pass function runs under :func:`timed_pass`, which writes a host
+span ``omp.pass.<name>`` into a running profiler trace (a
+``jax.profiler.TraceAnnotation`` costs nothing while none runs).  Inside
+``with PassClock() as clock:`` the same calls also add their wall
+seconds to ``clock.seconds``; :func:`repro.core.api.compile` keeps one
+clock per build and stores the seconds on each
+:class:`~repro.core.api.PassRecord`.  Passes nest (``plan_region`` runs
+the per-stage analyze/schedule/plan_comm passes inside ``plan``): a pass
+entered inside another pauses the outer one, so each second is counted
+once, for the innermost pass, and the seconds sum to the time spent in
+passes.
+
+:func:`stats` gives the process-wide totals: pass seconds of every
+finished clock, and the entries into the generated program's executor
+that ``Compiled.run`` records (:func:`add_executor`).
+"""
+from __future__ import annotations
+
+import contextlib
+import contextvars
+import threading
+import time
+
+from jax.profiler import TraceAnnotation
+
+_CLOCK: contextvars.ContextVar = contextvars.ContextVar(
+    "omp_pass_clock", default=None)
+_LOCK = threading.Lock()
+_PASS_SECONDS: dict[str, float] = {}
+_EXECUTOR = {"runs": 0, "seconds": 0.0}
+
+
+class PassClock:
+    """Wall seconds per pass name of the passes run while it is entered
+    (in this thread or task only: the clock is a context variable)."""
+
+    def __init__(self) -> None:
+        self.seconds: dict[str, float] = {}
+        self._open: list[list] = []     # [name, running since]
+        self._token = None
+
+    def __enter__(self) -> "PassClock":
+        self._token = _CLOCK.set(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _CLOCK.reset(self._token)
+        with _LOCK:
+            for name, s in self.seconds.items():
+                _PASS_SECONDS[name] = _PASS_SECONDS.get(name, 0.0) + s
+
+    def _charge(self, now: float) -> None:
+        """Add the time since the innermost open pass last resumed."""
+        top = self._open[-1]
+        self.seconds[top[0]] = self.seconds.get(top[0], 0.0) + now - top[1]
+        top[1] = now
+
+    def _start(self, name: str) -> None:
+        now = time.perf_counter()
+        if self._open:
+            self._charge(now)
+        self._open.append([name, now])
+
+    def _stop(self) -> None:
+        now = time.perf_counter()
+        self._charge(now)
+        self._open.pop()
+        if self._open:
+            self._open[-1][1] = now
+
+
+@contextlib.contextmanager
+def timed_pass(name: str):
+    """Run one pass (a ``with`` block, or a decorated function) under the
+    host span ``omp.pass.<name>``, timed by the entered
+    :class:`PassClock` if there is one."""
+    clock = _CLOCK.get()
+    with TraceAnnotation(f"omp.pass.{name}"):
+        if clock is None:
+            yield
+            return
+        clock._start(name)
+        try:
+            yield
+        finally:
+            clock._stop()
+
+
+def add_executor(seconds: float) -> None:
+    """Count one entry into a generated program's executor."""
+    with _LOCK:
+        _EXECUTOR["runs"] += 1
+        _EXECUTOR["seconds"] += seconds
+
+
+def stats() -> dict:
+    """Process-wide totals: ``pass_seconds`` (pass name -> wall seconds
+    of every finished :class:`PassClock`), ``executor_runs`` and
+    ``executor_seconds`` (entries into generated programs' executors
+    through ``Compiled.run``, and their wall seconds)."""
+    with _LOCK:
+        return {"pass_seconds": dict(_PASS_SECONDS),
+                "executor_runs": _EXECUTOR["runs"],
+                "executor_seconds": _EXECUTOR["seconds"]}

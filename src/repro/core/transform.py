@@ -171,7 +171,9 @@ class DistributedProgram:
     chunk_weights: tuple | None = None  # straggler-weighted chunk deal
 
     def __call__(self, env: Mapping[str, Any]) -> dict:
-        return _execute(self, {k: jnp.asarray(v) for k, v in env.items()})
+        env = {k: jnp.asarray(v) for k, v in env.items()}
+        with jax.named_scope(f"omp.block.{self.program.name}"):
+            return _execute(self, env)
 
     def report(self) -> str:
         from repro.core import report as report_mod
@@ -452,12 +454,14 @@ def _execute_collective(dp: DistributedProgram, env: dict) -> dict:
                  if plan.vars[k].in_strategy == "replicate"]
     env_repl = {k: env[k] for k in repl_keys}
     env_slab = {}
-    for k in plan.sharded_in_keys:
-        dec = plan.vars[k]
-        if dec.in_strategy == "shard_halo":
-            env_slab[k] = nest_mod.halo_slabs(env[k], plan.chunks, dec.halo)
-        else:
-            env_slab[k] = nest_mod.pad_reshape(env[k], plan.chunks)
+    with jax.named_scope("omp.entry"):
+        for k in plan.sharded_in_keys:
+            dec = plan.vars[k]
+            if dec.in_strategy == "shard_halo":
+                env_slab[k] = nest_mod.halo_slabs(env[k], plan.chunks,
+                                                  dec.halo)
+            else:
+                env_slab[k] = nest_mod.pad_reshape(env[k], plan.chunks)
 
     aggregate = dp.comm_schedule == "aggregate"
     if dp.use_pallas:
@@ -469,14 +473,17 @@ def _execute_collective(dp: DistributedProgram, env: dict) -> dict:
         from repro.core import comm_schedule as cs_mod
 
         d = jax.lax.axis_index(axis)
-        slab_stacks = {k: v[:, 0] for k, v in env_slab.items()}
-        if dp.use_pallas:
-            carry, ys = plx.run_local_chunks_pallas(
-                plan, program, env_repl, slab_stacks, d,
-                interpret=pallas_interp, device=mesh.devices.flat[0])
-        else:
-            carry, ys = _run_local_chunks(plan, program, env_repl,
-                                          slab_stacks, d, dp.unroll_chunks)
+        with jax.named_scope("omp.entry"):
+            slab_stacks = {k: v[:, 0] for k, v in env_slab.items()}
+        with jax.named_scope(f"omp.stage.{program.name}"):
+            if dp.use_pallas:
+                carry, ys = plx.run_local_chunks_pallas(
+                    plan, program, env_repl, slab_stacks, d,
+                    interpret=pallas_interp, device=mesh.devices.flat[0])
+            else:
+                carry, ys = _run_local_chunks(plan, program, env_repl,
+                                              slab_stacks, d,
+                                              dp.unroll_chunks)
 
         # With the aggregate schedule, every psum-family combine of the
         # block (scatter buf+mask pairs, put broadcasts, reduction
@@ -486,35 +493,41 @@ def _execute_collective(dp: DistributedProgram, env: dict) -> dict:
         pending: dict[tuple[str, str], tuple[str, Any]] = {}
         for key, dec in plan.vars.items():
             if dec.out_strategy in ("identity", "partial"):
-                outs[key] = ys[key][:, None]  # (n_loc, 1, c, *rest)
-            elif dec.out_strategy == "scatter":
-                buf, mask = carry[key]
-                if aggregate:
-                    pending[(key, "buf")] = ("psum", buf)
-                    pending[(key, "mask")] = ("psum", mask.astype(jnp.int32))
-                else:
-                    outs[key] = (
-                        jax.lax.psum(buf, axis),
-                        jax.lax.psum(mask.astype(jnp.int32), axis),
-                    )
-            elif dec.out_strategy == "put":
-                owner = plan.chunks.owner_of_last_iteration()
-                val = jnp.where(d == owner, carry[key],
-                                jnp.zeros_like(carry[key]))
-                if aggregate:
-                    pending[(key, "put")] = ("psum", val)
-                else:
-                    outs[key] = jax.lax.psum(val, axis)
-            elif dec.out_strategy == "reduce":
-                rop = red_mod.get_reduction(dec.reduction_op)
-                if rop.collective == "gather":
-                    outs[key] = carry[key][None]
-                elif aggregate:
-                    pending[(key, "red")] = (rop.collective, carry[key])
-                else:
-                    outs[key] = red_mod.cross_device_combine(rop, carry[key], axis)
+                with jax.named_scope("omp.exit"):
+                    outs[key] = ys[key][:, None]  # (n_loc, 1, c, *rest)
+                continue
+            with jax.named_scope(f"omp.combine.{key}"):
+                if dec.out_strategy == "scatter":
+                    buf, mask = carry[key]
+                    if aggregate:
+                        pending[(key, "buf")] = ("psum", buf)
+                        pending[(key, "mask")] = ("psum",
+                                                  mask.astype(jnp.int32))
+                    else:
+                        outs[key] = (
+                            jax.lax.psum(buf, axis),
+                            jax.lax.psum(mask.astype(jnp.int32), axis),
+                        )
+                elif dec.out_strategy == "put":
+                    owner = plan.chunks.owner_of_last_iteration()
+                    val = jnp.where(d == owner, carry[key],
+                                    jnp.zeros_like(carry[key]))
+                    if aggregate:
+                        pending[(key, "put")] = ("psum", val)
+                    else:
+                        outs[key] = jax.lax.psum(val, axis)
+                elif dec.out_strategy == "reduce":
+                    rop = red_mod.get_reduction(dec.reduction_op)
+                    if rop.collective == "gather":
+                        outs[key] = carry[key][None]
+                    elif aggregate:
+                        pending[(key, "red")] = (rop.collective, carry[key])
+                    else:
+                        outs[key] = red_mod.cross_device_combine(
+                            rop, carry[key], axis)
         if pending:
-            combined = cs_mod.fused_collectives(pending, axis)
+            with jax.named_scope("omp.combine"):
+                combined = cs_mod.fused_collectives(pending, axis)
             for key, dec in plan.vars.items():
                 if dec.out_strategy == "scatter":
                     outs[key] = (combined[(key, "buf")],
@@ -551,28 +564,32 @@ def _execute_collective(dp: DistributedProgram, env: dict) -> dict:
     # --- reassembly at the jit level (layout, not messages) ---------------
     result = dict(env)
     for key, dec in plan.vars.items():
-        if dec.out_strategy == "identity":
-            flat = nest_mod.unpad_flat(outs[key], plan.chunks, t)
-            result[key] = flat.astype(env[key].dtype)
-        elif dec.out_strategy == "partial":
-            flat = nest_mod.unpad_flat(outs[key], plan.chunks, t)
-            b = dec.write_map.b
-            result[key] = jax.lax.dynamic_update_slice_in_dim(
-                env[key], flat.astype(env[key].dtype), b, 0)
-        elif dec.out_strategy == "scatter":
-            summed, mask = outs[key]
-            vmask = (mask > 0).reshape((-1,) + (1,) * (summed.ndim - 1))
-            result[key] = jnp.where(vmask, summed.astype(env[key].dtype), env[key])
-        elif dec.out_strategy == "put":
-            result[key] = outs[key]
-        elif dec.out_strategy == "reduce":
-            rop = red_mod.get_reduction(dec.reduction_op)
-            val = outs[key]
-            if rop.collective == "gather":
-                val = rop.local_fold(val, 0)
-            if key in env:
-                val = rop.pairwise(env[key], val)
-            result[key] = val
+        if dec.out_strategy in ("identity", "partial"):
+            with jax.named_scope("omp.exit"):
+                flat = nest_mod.unpad_flat(outs[key], plan.chunks, t)
+                flat = flat.astype(env[key].dtype)
+                if dec.out_strategy == "identity":
+                    result[key] = flat
+                else:
+                    result[key] = jax.lax.dynamic_update_slice_in_dim(
+                        env[key], flat, dec.write_map.b, 0)
+            continue
+        with jax.named_scope(f"omp.combine.{key}"):
+            if dec.out_strategy == "scatter":
+                summed, mask = outs[key]
+                vmask = (mask > 0).reshape((-1,) + (1,) * (summed.ndim - 1))
+                result[key] = jnp.where(vmask, summed.astype(env[key].dtype),
+                                        env[key])
+            elif dec.out_strategy == "put":
+                result[key] = outs[key]
+            elif dec.out_strategy == "reduce":
+                rop = red_mod.get_reduction(dec.reduction_op)
+                val = outs[key]
+                if rop.collective == "gather":
+                    val = rop.local_fold(val, 0)
+                if key in env:
+                    val = rop.pairwise(env[key], val)
+                result[key] = val
     return result
 
 
@@ -696,15 +713,17 @@ def _execute_collective2(dp: DistributedProgram, env: dict) -> dict:
     env_repl = {k: env[k] for k in repl_keys}
     env_slab = {}
     slab_specs = {}
-    for k in plan.sharded_in_keys:
-        dec = plan.vars[k]
-        if dec.shard_ndim == 2:
-            env_slab[k] = nest_mod.halo_slabs2(
-                env[k], (ch_i, ch_j), dec.halo_axes)
-            slab_specs[k] = P(None, ax_i, None, None, ax_j, None)
-        else:
-            env_slab[k] = nest_mod.halo_slabs(env[k], ch_i, dec.halo_axes[0])
-            slab_specs[k] = P(None, ax_i, None)
+    with jax.named_scope("omp.entry"):
+        for k in plan.sharded_in_keys:
+            dec = plan.vars[k]
+            if dec.shard_ndim == 2:
+                env_slab[k] = nest_mod.halo_slabs2(
+                    env[k], (ch_i, ch_j), dec.halo_axes)
+                slab_specs[k] = P(None, ax_i, None, None, ax_j, None)
+            else:
+                env_slab[k] = nest_mod.halo_slabs(env[k], ch_i,
+                                                  dec.halo_axes[0])
+                slab_specs[k] = P(None, ax_i, None)
 
     aggregate = dp.comm_schedule == "aggregate"
     if dp.use_pallas:
@@ -718,35 +737,41 @@ def _execute_collective2(dp: DistributedProgram, env: dict) -> dict:
         d_i = jax.lax.axis_index(ax_i)
         d_j = jax.lax.axis_index(ax_j)
         slab_stacks = {}
-        for k, v in env_slab.items():
-            if plan.vars[k].shard_ndim == 2:
-                slab_stacks[k] = v[:, 0][:, :, :, 0]   # (n_i, w_i, n_j, w_j, *)
+        with jax.named_scope("omp.entry"):
+            for k, v in env_slab.items():
+                if plan.vars[k].shard_ndim == 2:
+                    # (n_i, w_i, n_j, w_j, *)
+                    slab_stacks[k] = v[:, 0][:, :, :, 0]
+                else:
+                    slab_stacks[k] = v[:, 0]           # (n_i, w_i, *rest)
+        with jax.named_scope(f"omp.stage.{program.name}"):
+            if dp.use_pallas:
+                carry, ys = plx.run_local_chunks_pallas2(
+                    plan, program, env_repl, slab_stacks, (d_i, d_j),
+                    interpret=pallas_interp, device=mesh.devices.flat[0])
             else:
-                slab_stacks[k] = v[:, 0]               # (n_i, w_i, *rest)
-        if dp.use_pallas:
-            carry, ys = plx.run_local_chunks_pallas2(
-                plan, program, env_repl, slab_stacks, (d_i, d_j),
-                interpret=pallas_interp, device=mesh.devices.flat[0])
-        else:
-            carry, ys = _run_local_chunks2(plan, program, env_repl,
-                                           slab_stacks, (d_i, d_j),
-                                           dp.unroll_chunks)
+                carry, ys = _run_local_chunks2(plan, program, env_repl,
+                                               slab_stacks, (d_i, d_j),
+                                               dp.unroll_chunks)
         outs: dict[str, Any] = {}
         reduce_items: dict[str, tuple] = {}
         for key, dec in plan.vars.items():
             if dec.out_strategy in ("identity", "partial"):
                 # (n_i, c_i, n_j, c_j, *) -> (n_i, 1, c_i, n_j, 1, c_j, *)
-                outs[key] = ys[key][:, None, :, :, None]
+                with jax.named_scope("omp.exit"):
+                    outs[key] = ys[key][:, None, :, :, None]
             elif dec.out_strategy == "reduce":
                 rop = red_mod.get_reduction(dec.reduction_op)
                 if aggregate:
                     reduce_items[key] = (rop, carry[key])
                 else:
-                    outs[key] = red_mod.cross_device_combine(
-                        rop, carry[key], (ax_i, ax_j))
+                    with jax.named_scope(f"omp.combine.{key}"):
+                        outs[key] = red_mod.cross_device_combine(
+                            rop, carry[key], (ax_i, ax_j))
         if reduce_items:
-            outs.update(cs_mod.fused_cross_device_combine(
-                reduce_items, (ax_i, ax_j)))
+            with jax.named_scope("omp.combine"):
+                outs.update(cs_mod.fused_cross_device_combine(
+                    reduce_items, (ax_i, ax_j)))
         return outs
 
     in_specs = ({k: P() for k in env_repl}, slab_specs)
@@ -767,19 +792,22 @@ def _execute_collective2(dp: DistributedProgram, env: dict) -> dict:
     result = dict(env)
     for key, dec in plan.vars.items():
         if dec.out_strategy == "identity":
-            flat = nest_mod.unpad_flat2(outs[key], (ch_i, ch_j), trips)
-            result[key] = flat.astype(env[key].dtype)
+            with jax.named_scope("omp.exit"):
+                flat = nest_mod.unpad_flat2(outs[key], (ch_i, ch_j), trips)
+                result[key] = flat.astype(env[key].dtype)
         elif dec.out_strategy == "partial":
-            flat = nest_mod.unpad_flat2(outs[key], (ch_i, ch_j), trips)
-            starts = (dec.write_maps[0].b, dec.write_maps[1].b) \
-                + (0,) * (flat.ndim - 2)
-            result[key] = jax.lax.dynamic_update_slice(
-                env[key], flat.astype(env[key].dtype), starts)
+            with jax.named_scope("omp.exit"):
+                flat = nest_mod.unpad_flat2(outs[key], (ch_i, ch_j), trips)
+                starts = (dec.write_maps[0].b, dec.write_maps[1].b) \
+                    + (0,) * (flat.ndim - 2)
+                result[key] = jax.lax.dynamic_update_slice(
+                    env[key], flat.astype(env[key].dtype), starts)
         elif dec.out_strategy == "reduce":
             rop = red_mod.get_reduction(dec.reduction_op)
             val = outs[key]
             if key in env:
-                val = rop.pairwise(env[key], val)
+                with jax.named_scope(f"omp.combine.{key}"):
+                    val = rop.pairwise(env[key], val)
             result[key] = val
     return result
 
@@ -811,106 +839,116 @@ def _execute_master_worker(dp: DistributedProgram, env: dict) -> dict:
         # --- master -> worker sends of every IN buffer --------------------
         env_in: dict[str, Any] = {}
         slab_stacks: dict[str, Any] = {}
-        for key in plan.context.env_keys:
-            dec = plan.vars[key]
-            info = plan.context.vars[key]
-            if dec.in_strategy == "replicate":
-                x = env_all[key]
-                recv = x
-                for dst in range(first_worker, p_total):
-                    if dst == 0:
-                        continue
-                    recv = _mw_send(x, 0, dst, d, recv, axis)
-                env_in[key] = recv
-            elif dec.in_strategy == "shard":
-                x_pad = env_all[key]  # already (n_loc, W, c, *rest)
-                my = jnp.take(x_pad, wd, axis=1)
-                for dst_w in range(w):
-                    dst = dst_w + first_worker
-                    if dst == 0:
-                        continue
-                    slab = x_pad[:, dst_w]
-                    my = _mw_send(slab, 0, dst, d, my, axis)
-                slab_stacks[key] = my
-            else:
-                env_in[key] = jnp.zeros(info.shape, info.dtype)
+        with jax.named_scope("omp.entry"):
+            for key in plan.context.env_keys:
+                dec = plan.vars[key]
+                info = plan.context.vars[key]
+                if dec.in_strategy == "replicate":
+                    x = env_all[key]
+                    recv = x
+                    for dst in range(first_worker, p_total):
+                        if dst == 0:
+                            continue
+                        recv = _mw_send(x, 0, dst, d, recv, axis)
+                    env_in[key] = recv
+                elif dec.in_strategy == "shard":
+                    x_pad = env_all[key]  # already (n_loc, W, c, *rest)
+                    my = jnp.take(x_pad, wd, axis=1)
+                    for dst_w in range(w):
+                        dst = dst_w + first_worker
+                        if dst == 0:
+                            continue
+                        slab = x_pad[:, dst_w]
+                        my = _mw_send(slab, 0, dst, d, my, axis)
+                    slab_stacks[key] = my
+                else:
+                    env_in[key] = jnp.zeros(info.shape, info.dtype)
 
-        carry, ys = _run_local_chunks(plan, program, env_in, slab_stacks, wd,
-                                      dp.unroll_chunks)
+        with jax.named_scope(f"omp.stage.{program.name}"):
+            carry, ys = _run_local_chunks(plan, program, env_in, slab_stacks,
+                                          wd, dp.unroll_chunks)
 
         outs: dict[str, Any] = {}
         for key, dec in plan.vars.items():
             info = plan.context.vars[key]
-            if dec.out_strategy in ("identity", "partial"):
-                # workers -> master sends of each slab stack, master
-                # assembles the padded buffer, then re-broadcasts it.
-                full = jnp.zeros((ch.padded_trip,) + info.shape[1:], info.dtype)
-                for src_w in range(w):
-                    src = src_w + first_worker
-                    stack = ys[key]  # (n_loc, c, *rest)
-                    if src != 0:
-                        got = jax.lax.ppermute(stack, axis, perm=[(src, 0)])
-                    else:
-                        got = stack
-                    rows = np.concatenate([
-                        np.arange(ch.chunk) + (q * w + src_w) * ch.chunk
-                        for q in range(ch.local_chunks)
-                    ])
-                    flat = got.reshape((-1,) + info.shape[1:])
-                    placed = full.at[rows].set(flat)
-                    full = jnp.where(d == 0, placed, full)
-                for dst in range(first_worker, p_total):
-                    if dst == 0:
-                        continue
-                    full = _mw_send(full, 0, dst, d, full, axis)
-                outs[key] = full[None]
-            elif dec.out_strategy == "scatter":
-                buf, mask = carry[key]
-                if first_worker == 1:
-                    # The excluded master duplicated worker 0's chunks
-                    # (clamped wd); drop its contribution before combining.
-                    is_worker = (d >= 1).astype(buf.dtype)
-                    buf = buf * is_worker.reshape((1,) * buf.ndim)
-                    mask = jnp.logical_and(mask, d >= 1)
-                outs[key] = (
-                    jax.lax.psum(buf, axis),
-                    jax.lax.psum(mask.astype(jnp.int32), axis),
-                )
-            elif dec.out_strategy == "put":
-                j_star = (t - 1) // ch.chunk
-                owner = j_star % w + first_worker
-                val = carry[key]
-                if owner != 0:
-                    val = _mw_send(val, owner, 0, d, val, axis)
-                for dst in range(first_worker, p_total):
-                    if dst == 0:
-                        continue
-                    val = _mw_send(val, 0, dst, d, val, axis)
-                outs[key] = val[None]
-            elif dec.out_strategy == "reduce":
-                # Table 3: workers send partials; the master folds them in
-                # rank order into the identity-initialised accumulator.
-                rop = red_mod.get_reduction(dec.reduction_op)
-                acc = red_mod.identity_like(rop, carry[key])
-                for src_w in range(w):
-                    src = src_w + first_worker
-                    if src == 0:  # master computed its own chunks
-                        acc = jnp.where(d == 0, rop.pairwise(acc, carry[key]), acc)
-                        continue
-                    got = jax.lax.ppermute(carry[key], axis, perm=[(src, 0)])
-                    acc = jnp.where(d == 0, rop.pairwise(acc, got), acc)
-                for dst in range(first_worker, p_total):
-                    if dst == 0:
-                        continue
-                    acc = _mw_send(acc, 0, dst, d, acc, axis)
-                outs[key] = acc[None]
+            scope = ("omp.exit" if dec.out_strategy in ("identity", "partial")
+                     else f"omp.combine.{key}")
+            with jax.named_scope(scope):
+                if dec.out_strategy in ("identity", "partial"):
+                    # workers -> master sends of each slab stack, master
+                    # assembles the padded buffer, then re-broadcasts it.
+                    full = jnp.zeros((ch.padded_trip,) + info.shape[1:],
+                                     info.dtype)
+                    for src_w in range(w):
+                        src = src_w + first_worker
+                        stack = ys[key]  # (n_loc, c, *rest)
+                        if src != 0:
+                            got = jax.lax.ppermute(stack, axis,
+                                                   perm=[(src, 0)])
+                        else:
+                            got = stack
+                        rows = np.concatenate([
+                            np.arange(ch.chunk) + (q * w + src_w) * ch.chunk
+                            for q in range(ch.local_chunks)
+                        ])
+                        flat = got.reshape((-1,) + info.shape[1:])
+                        placed = full.at[rows].set(flat)
+                        full = jnp.where(d == 0, placed, full)
+                    for dst in range(first_worker, p_total):
+                        if dst == 0:
+                            continue
+                        full = _mw_send(full, 0, dst, d, full, axis)
+                    outs[key] = full[None]
+                elif dec.out_strategy == "scatter":
+                    buf, mask = carry[key]
+                    if first_worker == 1:
+                        # The excluded master duplicated worker 0's chunks
+                        # (clamped wd); drop its contribution before combining.
+                        is_worker = (d >= 1).astype(buf.dtype)
+                        buf = buf * is_worker.reshape((1,) * buf.ndim)
+                        mask = jnp.logical_and(mask, d >= 1)
+                    outs[key] = (
+                        jax.lax.psum(buf, axis),
+                        jax.lax.psum(mask.astype(jnp.int32), axis),
+                    )
+                elif dec.out_strategy == "put":
+                    j_star = (t - 1) // ch.chunk
+                    owner = j_star % w + first_worker
+                    val = carry[key]
+                    if owner != 0:
+                        val = _mw_send(val, owner, 0, d, val, axis)
+                    for dst in range(first_worker, p_total):
+                        if dst == 0:
+                            continue
+                        val = _mw_send(val, 0, dst, d, val, axis)
+                    outs[key] = val[None]
+                elif dec.out_strategy == "reduce":
+                    # Table 3: workers send partials; the master folds them in
+                    # rank order into the identity-initialised accumulator.
+                    rop = red_mod.get_reduction(dec.reduction_op)
+                    acc = red_mod.identity_like(rop, carry[key])
+                    for src_w in range(w):
+                        src = src_w + first_worker
+                        if src == 0:  # master computed its own chunks
+                            acc = jnp.where(
+                                d == 0, rop.pairwise(acc, carry[key]), acc)
+                            continue
+                        got = jax.lax.ppermute(carry[key], axis,
+                                               perm=[(src, 0)])
+                        acc = jnp.where(d == 0, rop.pairwise(acc, got), acc)
+                    for dst in range(first_worker, p_total):
+                        if dst == 0:
+                            continue
+                        acc = _mw_send(acc, 0, dst, d, acc, axis)
+                    outs[key] = acc[None]
         return outs
 
     env_all = {}
     for key in plan.context.env_keys:
         dec = plan.vars[key]
         if dec.in_strategy == "shard":
-            env_all[key] = nest_mod.pad_reshape(env[key], plan.chunks)
+            with jax.named_scope("omp.entry"):
+                env_all[key] = nest_mod.pad_reshape(env[key], plan.chunks)
         else:
             env_all[key] = env[key]
     in_specs = {k: P() for k in env_all}
@@ -929,22 +967,26 @@ def _execute_master_worker(dp: DistributedProgram, env: dict) -> dict:
 
     result = dict(env)
     for key, dec in plan.vars.items():
-        if dec.out_strategy == "identity":
-            result[key] = outs[key][0][:t]
-        elif dec.out_strategy == "partial":
-            flat = outs[key][0][:t]
-            result[key] = jax.lax.dynamic_update_slice_in_dim(
-                env[key], flat.astype(env[key].dtype), dec.write_map.b, 0)
-        elif dec.out_strategy == "scatter":
-            summed, mask = outs[key]
-            vmask = (mask > 0).reshape((-1,) + (1,) * (summed.ndim - 1))
-            result[key] = jnp.where(vmask, summed.astype(env[key].dtype), env[key])
-        elif dec.out_strategy == "put":
-            result[key] = outs[key][0]
-        elif dec.out_strategy == "reduce":
-            rop = red_mod.get_reduction(dec.reduction_op)
-            val = outs[key][0]
-            if key in env:
-                val = rop.pairwise(env[key], val)
-            result[key] = val
+        scope = ("omp.exit" if dec.out_strategy in ("identity", "partial")
+                 else f"omp.combine.{key}")
+        with jax.named_scope(scope):
+            if dec.out_strategy == "identity":
+                result[key] = outs[key][0][:t]
+            elif dec.out_strategy == "partial":
+                flat = outs[key][0][:t]
+                result[key] = jax.lax.dynamic_update_slice_in_dim(
+                    env[key], flat.astype(env[key].dtype), dec.write_map.b, 0)
+            elif dec.out_strategy == "scatter":
+                summed, mask = outs[key]
+                vmask = (mask > 0).reshape((-1,) + (1,) * (summed.ndim - 1))
+                result[key] = jnp.where(
+                    vmask, summed.astype(env[key].dtype), env[key])
+            elif dec.out_strategy == "put":
+                result[key] = outs[key][0]
+            elif dec.out_strategy == "reduce":
+                rop = red_mod.get_reduction(dec.reduction_op)
+                val = outs[key][0]
+                if key in env:
+                    val = rop.pairwise(env[key], val)
+                result[key] = val
     return result

@@ -31,6 +31,7 @@ from repro.core.api import (  # noqa: F401
     enable_persistent_cache,
 )
 from repro.core.aot_store import AOTStore  # noqa: F401
+from repro.core.timing import stats as timing_stats  # noqa: F401
 from repro.core.context import (  # noqa: F401
     Affine,
     ContextInfo,
