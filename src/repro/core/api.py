@@ -998,7 +998,10 @@ class Compiled:
     * ``.cost_summary()`` — modeled communication totals as a dict,
     * ``.cache_hit``    — whether the last build came from the cache,
     * ``.executor_runs`` / ``.executor_seconds`` — entries into the
-      executor from :meth:`run`, and their wall seconds.
+      executor from :meth:`run`, and their wall seconds,
+    * ``.chunk_eval_sliced`` / ``.chunk_eval_scan`` — rank-2 stages
+      those entries traced on the sliced chunk evaluator / on the
+      chunk scan.
 
     The pipeline needs environment *shapes*; compile with ``env_like=``
     to run it eagerly, otherwise it runs (through the compilation
@@ -1018,6 +1021,12 @@ class Compiled:
     compiles again."""
     executor_seconds: float = dataclasses.field(default=0.0, compare=False)
     """Wall seconds of those entries (host span ``omp.executor``)."""
+    chunk_eval_sliced: int = dataclasses.field(default=0, compare=False)
+    """Rank-2 stages those entries evaluated over the whole local chunk
+    stack, window reads served as slices."""
+    chunk_eval_scan: int = dataclasses.field(default=0, compare=False)
+    """Rank-2 stages those entries ran as a scan of vmapped chunks (a
+    window read the sliced evaluator cannot serve)."""
     _exe: Any = dataclasses.field(default=None, repr=False)
     _passes: tuple | None = dataclasses.field(default=None, repr=False)
     _env_sig: tuple | None = dataclasses.field(default=None, repr=False)
@@ -1048,13 +1057,17 @@ class Compiled:
             if self._exe is None:
                 self._ensure(env, allow_restore=False)
             t0 = time.perf_counter()
+            tally = {"sliced": 0, "scan": 0}
             try:
-                with TraceAnnotation("omp.executor"):
+                with TraceAnnotation("omp.executor"), \
+                        timing.chunk_tally(tally):
                     out = self._exe(env)
             finally:
                 seconds = time.perf_counter() - t0
                 self.executor_runs += 1
                 self.executor_seconds += seconds
+                self.chunk_eval_sliced += tally["sliced"]
+                self.chunk_eval_scan += tally["scan"]
                 timing.add_executor(seconds)
         if _fault_hook is not None:
             out = _fault_hook("run_exit", out)

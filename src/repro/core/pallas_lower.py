@@ -22,14 +22,17 @@ rows it reads, halo included) enters as one block indexed by the chunk,
 with the chunk index squeezed away; halo windows overlap between chunks,
 so halo-awareness lives in the static offset of each read
 ``x[i+b]`` inside the block (``b - b_min``) rather than in the BlockSpec.
-Outputs leave as one block per tile.
+Outputs leave as one block per tile.  Inside the kernel the body is
+evaluated over the tile by :mod:`repro.core.tile_eval`, which serves
+each window read as a static slice of the loaded block.
 
 The kernel produces only dense per-lane body values; every merge
 (scatter/put/reduce folds, slab state updates, cross-device combines)
-runs outside on the sliced values via :func:`merge_chunk_values`,
-which reproduces the ``(carry, ys)`` contract of ``_run_local_chunks``
-bit-for-bit — that is what lets the differential test wall pin the
-backend against the lax lowering and the shared-memory reference.
+runs outside on the sliced values via
+:func:`~repro.core.tile_eval.merge_chunk_values`, which reproduces the
+``(carry, ys)`` contract of ``_run_local_chunks`` bit-for-bit — that
+is what lets the differential test wall pin the backend against the
+lax lowering and the shared-memory reference.
 
 Off-TPU (the CPU tests) the kernels run in interpret mode;
 ``Options(pallas_interpret=...)`` forces either mode, ``None`` picks
@@ -44,12 +47,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from jax.extend import core as jcore
-
-from repro.core import context as ctx_mod
 from repro.core import nest as nest_mod
-from repro.core import reduction as red_mod
-from repro.core.nest import AxisTiles, NestAffine, derive_axis_tiles
+from repro.core.nest import AxisTiles, derive_axis_tiles
+from repro.core.tile_eval import (Src, eval_body, full, merge_chunk_values,
+                                  merge_chunk_values2, trace_body)
 from repro.core.timing import timed_pass
 
 from jax.experimental import pallas as pl
@@ -285,206 +286,6 @@ _VMEM_BYTES = {"TPU v5 lite": 128 << 20, "TPU v5e": 128 << 20,
 
 
 # ---------------------------------------------------------------------------
-# Tile evaluation of a loop body
-#
-# The body is traced once on scalar iterators (as Context Analysis traces
-# it) and its jaxpr is evaluated over a whole tile: every equation runs
-# batched over the tile's lanes through ``jax.vmap``, except the reads
-# ``x[i+b]`` / ``x[i+b, j+c]`` of a chunk window, which become static
-# slices of the loaded block.  A vmapped body would turn those reads into
-# gathers, which Mosaic cannot lower.
-# ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class _Body:
-    closed: Any                # ClosedJaxpr of body(i[, j], env)
-    env_keys: tuple            # env keys in invar order
-    value_pos: dict            # written key -> flat position of its value
-
-
-def _trace_body(plan, program) -> _Body:
-    infos = plan.context.vars
-    keys = tuple(sorted(plan.context.env_keys))
-    env = {k: jax.ShapeDtypeStruct(infos[k].shape, infos[k].dtype)
-           for k in keys}
-    it = jax.ShapeDtypeStruct((), jnp.int32)
-    closed, out_shape = jax.make_jaxpr(program.body, return_shape=True)(
-        *(it,) * plan.rank, env)
-    leaves, tree = jax.tree_util.tree_flatten(out_shape)
-    pos = jax.tree_util.tree_unflatten(tree, list(range(len(leaves))))
-    return _Body(closed, keys, {k: u.value for k, u in pos.items()})
-
-
-@dataclasses.dataclass
-class _Src:
-    """An env buffer as the kernel sees it: ``win`` (the tile's region of
-    a chunk window, or a forwarded tile; sharded axes lead and row 0 of
-    axis ``d`` is lane 0 shifted by ``origin[d]``), ``val`` (a replicated
-    array) or ``zeros`` (a buffer the stage never reads)."""
-
-    kind: str
-    value: Any = None
-    origin: tuple = ()
-    info: Any = None
-
-
-@dataclasses.dataclass
-class _Read:
-    """A served window read whose unit per-lane axes (the ``r`` sharded
-    ones) are not materialised yet: the ``squeeze`` that jnp indexing
-    emits next drops them for free."""
-
-    value: Any
-    mask: tuple
-    r: int
-
-
-def _is_var(v) -> bool:
-    return isinstance(v, jcore.Var)
-
-
-def _live_eqns(jaxpr, want, windows) -> set:
-    live = {v for v in want if _is_var(v)}
-    keep = set()
-    for n in range(len(jaxpr.eqns) - 1, -1, -1):
-        eqn = jaxpr.eqns[n]
-        if not any(ov in live for ov in eqn.outvars):
-            continue
-        keep.add(n)
-        ins = eqn.invars
-        if eqn.primitive.name == "dynamic_slice" and ins[0] in windows:
-            ins = ins[:1]                   # served as a static slice
-        live.update(v for v in ins if _is_var(v))
-    return keep
-
-
-def _apply_batched(eqn, ins, nax: int):
-    """One equation over batched operands (leading dims = the batched
-    lane axes, in axis order): nested ``jax.vmap``, outermost axis 0."""
-    subfuns, params = eqn.primitive.get_bind_params(eqn.params)
-
-    def f(*args):
-        return eqn.primitive.bind(*subfuns, *args, **params)
-
-    masks = [m for _, m in ins]
-    out_mask = tuple(any(m[a] for m in masks) for a in range(nax))
-    g = f
-    for a in reversed(range(nax)):
-        if out_mask[a]:
-            g = jax.vmap(g, in_axes=tuple(0 if m[a] else None
-                                          for m in masks))
-    return g(*(x for x, _ in ins)), out_mask
-
-
-def _serve_read(eqn, src: _Src, aff, plan, lanes):
-    """``dynamic_slice(x, i+b, ...)`` of a window -> the tile's slice."""
-    nax = len(lanes)
-    starts = [aff.lookup(a) for a in eqn.invars[1:]]
-    sizes = eqn.params["slice_sizes"]
-    r = len(src.origin)
-    v = src.value
-    for d in range(r):
-        unit = tuple(int(a == d) for a in range(nax))
-        km = starts[d].k_space(plan.nest) if starts[d] is not None else None
-        s = None if km is None or km.coeffs != unit else km.b - src.origin[d]
-        if sizes[d] != 1 or s is None or s < 0 \
-                or s + lanes[d] > v.shape[d]:
-            raise nest_mod.SubstitutionFailed(
-                f"read of axis {d} at {starts[d]!r} is not a unit-stride "
-                "read inside the chunk window")
-        v = jax.lax.slice_in_dim(v, s, s + lanes[d], axis=d)
-    for d in range(r, len(sizes)):
-        m, size, dim = starts[d], sizes[d], v.shape[d]
-        if m is None or not m.is_const:
-            raise nest_mod.SubstitutionFailed(
-                f"read of unsharded axis {d} at {m!r} is not constant")
-        if size != dim:
-            c = min(max(m.b, 0), dim - size)    # dynamic_slice clamps
-            v = jax.lax.slice_in_dim(v, c, c + size, axis=d)
-    v = v.astype(eqn.outvars[0].aval.dtype)
-    return _Read(v, tuple(a < r for a in range(nax)), r)
-
-
-def _materialize(rd: _Read):
-    nb = sum(rd.mask)
-    v = rd.value
-    return v.reshape(v.shape[:nb] + (1,) * rd.r + v.shape[nb:]), rd.mask
-
-
-def _full(v, mask, lanes):
-    """Broadcast a batched value over every lane axis."""
-    v = jnp.asarray(v)
-    for a, m in enumerate(mask):
-        if not m:
-            v = jnp.expand_dims(v, a)
-    return jnp.broadcast_to(v, tuple(lanes) + v.shape[len(lanes):])
-
-
-def _eval_body(body: _Body, plan, ivals, srcs, lanes, keys_out) -> dict:
-    """Evaluate a loop body over one tile; returns ``key -> (value,
-    batch mask)`` for every written key in ``keys_out``."""
-    jaxpr = body.closed.jaxpr
-    nax = len(lanes)
-    none = (False,) * nax
-    vals: dict = {}
-    for v, c in zip(jaxpr.constvars, body.closed.consts):
-        vals[v] = (c, none)
-    for v, iv in zip(jaxpr.invars[:nax], ivals):
-        vals[v] = iv
-    src_of = dict(zip(jaxpr.invars[nax:], (srcs[k] for k in body.env_keys)))
-    want = [jaxpr.outvars[body.value_pos[k]] for k in keys_out]
-    windows = {v for v, src in src_of.items() if src.kind == "win"}
-    live = _live_eqns(jaxpr, want, windows)
-    aff = ctx_mod._AffineEnv(
-        {v: NestAffine(tuple(int(a == d) for a in range(nax)), 0)
-         for d, v in enumerate(jaxpr.invars[:nax])},
-        const=lambda c: NestAffine((0,) * nax, c))
-
-    def read(v):
-        if not _is_var(v):
-            return v.val, none
-        src = src_of.get(v)
-        if src is not None:
-            if src.kind == "val":
-                return src.value, none
-            if src.kind == "zeros":
-                return jnp.zeros(src.info.shape, src.info.dtype), none
-            raise nest_mod.SubstitutionFailed(
-                "a chunk-window buffer is used other than through "
-                "x[i]-style reads")
-        got = vals[v]
-        return _materialize(got) if isinstance(got, _Read) else got
-
-    for n, eqn in enumerate(jaxpr.eqns):
-        aff.process(eqn)
-        if n not in live:
-            continue
-        prim = eqn.primitive.name
-        x0 = eqn.invars[0] if eqn.invars else None
-        if prim == "dynamic_slice" and x0 in windows:
-            vals[eqn.outvars[0]] = _serve_read(eqn, src_of[x0], aff, plan,
-                                               lanes)
-            continue
-        pending = vals.get(x0) if _is_var(x0) else None
-        dims = eqn.params.get("dimensions", ())
-        if prim == "squeeze" and isinstance(pending, _Read) \
-                and set(range(pending.r)) <= set(dims):
-            nb = sum(pending.mask)
-            rest = tuple(nb + d - pending.r for d in dims if d >= pending.r)
-            v = jax.lax.squeeze(pending.value, rest) if rest \
-                else pending.value
-            vals[eqn.outvars[0]] = (v, pending.mask)
-            continue
-        outs, mask = _apply_batched(eqn, [read(v) for v in eqn.invars], nax)
-        if not eqn.primitive.multiple_results:
-            outs = [outs]
-        for ov, o in zip(eqn.outvars, outs):
-            vals[ov] = (o, mask)
-    return {k: read(w) for k, w in zip(keys_out, want)}
-
-
-# ---------------------------------------------------------------------------
 # Kernel inputs and outputs
 #
 # Mosaic wants the last two dims of every block divisible by (sublane,
@@ -672,7 +473,7 @@ def execute_span(stages: list[SpanStage], device_indices: tuple,
     inputs, out_shapes, out_specs, out_keys = _collect_io(stages, rank, tiles)
     if not out_keys:
         return [({}, {}) for _ in stages]
-    bodies = [_trace_body(sp.plan, sp.program) for sp in stages]
+    bodies = [trace_body(sp.plan, sp.program) for sp in stages]
     out_index = {k: oi for oi, k in enumerate(out_keys)}
     meta = jnp.stack([jnp.asarray(d, jnp.int32) for d in device_indices])
     n_in = len(inputs)
@@ -704,19 +505,19 @@ def execute_span(stages: list[SpanStage], device_indices: tuple,
                 dec = plan.vars[key]
                 if dec.in_strategy in ("shard", "shard_halo"):
                     r = dec.shard_ndim if rank == 2 else 1
-                    srcs[key] = _Src(
+                    srcs[key] = Src(
                         "win", span_vals[key] if key in sp.forwarded
                         else loaded[(si, key)],
                         tuple(_halo_base(dec, a) for a in range(r)))
                 elif dec.in_strategy == "replicate":
-                    srcs[key] = _Src("val", loaded[(si, key)])
+                    srcs[key] = Src("val", loaded[(si, key)])
                 else:
-                    srcs[key] = _Src("zeros", info=plan.context.vars[key])
+                    srcs[key] = Src("zeros", info=plan.context.vars[key])
             keys_out = [k for (osi, k) in out_keys if osi == si]
-            got = _eval_body(body, plan, ivals, srcs, lanes, keys_out)
+            got = eval_body(body, plan, ivals, srcs, lanes, keys_out)
             for key in keys_out:
                 oi = out_index[(si, key)]
-                v = _full(*got[key], lanes).astype(out_shapes[oi].dtype)
+                v = full(*got[key], lanes).astype(out_shapes[oi].dtype)
                 out_refs[oi][...] = v[None] if v.ndim == 1 else v
                 if plan.vars[key].out_strategy in ("identity", "partial"):
                     span_vals[key] = v
@@ -785,95 +586,4 @@ def run_local_chunks_pallas2(plan, program, env_in, slab_stacks,
                    forwarded=frozenset())
     (carry, ys), = execute_span([sp], tuple(device_indices), interpret,
                                 device)
-    return carry, ys
-
-
-# ---------------------------------------------------------------------------
-# Merges — outside the kernel, reproducing the _run_local_chunks /
-# _run_local_chunks2 (carry, ys) contract from dense per-lane values
-# ---------------------------------------------------------------------------
-
-
-def merge_chunk_values(plan, values, device_index):
-    """(n_loc, c, *value_shape) dense values -> (carry, ys) exactly as
-    ``_run_local_chunks`` would have produced them."""
-    ch = plan.chunks
-    t = plan.loop.trip_count
-    js = (jnp.arange(ch.local_chunks, dtype=jnp.int32) * ch.num_devices
-          + device_index)
-    ks = (js[:, None] * ch.chunk
-          + jnp.arange(ch.chunk, dtype=jnp.int32)[None, :])
-    valid = ks < t
-    carry: dict[str, Any] = {}
-    ys: dict[str, Any] = {}
-    for key, dec in plan.vars.items():
-        if dec.out_strategy == "none":
-            continue
-        v = values[key]
-        info = plan.context.vars[key]
-        if dec.out_strategy in ("identity", "partial"):
-            ys[key] = v
-        elif dec.out_strategy == "scatter":
-            shape0 = info.shape[0]
-            pos = dec.write_map.a * ks + dec.write_map.b
-            pos = jnp.where(valid, pos, shape0).reshape(-1)
-            flat = v.reshape((-1,) + v.shape[2:])
-            buf = jnp.zeros(info.shape, info.dtype) \
-                .at[pos].set(flat, mode="drop")
-            mask = jnp.zeros((shape0,), jnp.bool_) \
-                .at[pos].set(True, mode="drop")
-            carry[key] = (buf, mask)
-        elif dec.out_strategy == "put":
-            j_star = (t - 1) // ch.chunk
-            lane = (t - 1) - j_star * ch.chunk
-            q_star = j_star // ch.num_devices
-            row = v[q_star, lane]
-            carry[key] = jnp.where(js[q_star] == j_star, row,
-                                   jnp.zeros(info.shape, info.dtype))
-        elif dec.out_strategy == "reduce":
-            rop = red_mod.get_reduction(dec.reduction_op)
-            ident = red_mod.identity_like(rop, v)
-            vmask = valid.reshape(valid.shape + (1,) * (v.ndim - 2))
-            flat = jnp.where(vmask, v, ident) \
-                .reshape((-1,) + v.shape[2:])
-            carry0 = red_mod.identity_like(
-                rop, jnp.zeros(info.write.value_shape,
-                               info.write.value_dtype))
-            carry[key] = rop.pairwise(carry0, rop.local_fold(flat, 0))
-    return carry, ys
-
-
-def merge_chunk_values2(plan, values, device_indices):
-    """(n_i, c_i, n_j, c_j, *value_shape) dense values -> (carry, ys)
-    exactly as ``_run_local_chunks2`` would have produced them."""
-    ch_i, ch_j = plan.chunks_axes
-    loop_i, loop_j = plan.nest.axes
-    d_i, d_j = device_indices
-    ks_i = ((jnp.arange(ch_i.local_chunks, dtype=jnp.int32)
-             * ch_i.num_devices + d_i)[:, None] * ch_i.chunk
-            + jnp.arange(ch_i.chunk, dtype=jnp.int32)[None, :])
-    ks_j = ((jnp.arange(ch_j.local_chunks, dtype=jnp.int32)
-             * ch_j.num_devices + d_j)[:, None] * ch_j.chunk
-            + jnp.arange(ch_j.chunk, dtype=jnp.int32)[None, :])
-    valid = (ks_i < loop_i.trip_count)[:, :, None, None] \
-        & (ks_j < loop_j.trip_count)[None, None, :, :]
-    carry: dict[str, Any] = {}
-    ys: dict[str, Any] = {}
-    for key, dec in plan.vars.items():
-        if dec.out_strategy == "none":
-            continue
-        v = values[key]
-        info = plan.context.vars[key]
-        if dec.out_strategy in ("identity", "partial"):
-            ys[key] = v
-        elif dec.out_strategy == "reduce":
-            rop = red_mod.get_reduction(dec.reduction_op)
-            ident = red_mod.identity_like(rop, v)
-            vmask = valid.reshape(valid.shape + (1,) * (v.ndim - 4))
-            flat = jnp.where(vmask, v, ident) \
-                .reshape((-1,) + v.shape[4:])
-            carry0 = red_mod.identity_like(
-                rop, jnp.zeros(info.write.value_shape,
-                               info.write.value_dtype))
-            carry[key] = rop.pairwise(carry0, rop.local_fold(flat, 0))
     return carry, ys

@@ -14,8 +14,10 @@ once, for the innermost pass, and the seconds sum to the time spent in
 passes.
 
 :func:`stats` gives the process-wide totals: pass seconds of every
-finished clock, and the entries into the generated program's executor
-that ``Compiled.run`` records (:func:`add_executor`).
+finished clock, the entries into the generated program's executor
+that ``Compiled.run`` records (:func:`add_executor`), and how many
+rank-2 stages the executor traced on the sliced chunk evaluator or on the
+chunk scan (:func:`count_chunk_eval`).
 """
 from __future__ import annotations
 
@@ -31,6 +33,9 @@ _CLOCK: contextvars.ContextVar = contextvars.ContextVar(
 _LOCK = threading.Lock()
 _PASS_SECONDS: dict[str, float] = {}
 _EXECUTOR = {"runs": 0, "seconds": 0.0}
+_CHUNK_EVAL = {"sliced": 0, "scan": 0}
+_CHUNK_TALLY: contextvars.ContextVar = contextvars.ContextVar(
+    "omp_chunk_tally", default=None)
 
 
 class PassClock:
@@ -96,12 +101,38 @@ def add_executor(seconds: float) -> None:
         _EXECUTOR["seconds"] += seconds
 
 
+def count_chunk_eval(path: str) -> None:
+    """Count one rank-2 stage traced on the ``"sliced"`` chunk evaluator
+    or the ``"scan"`` of vmapped chunks, in the process totals and in
+    the innermost :func:`chunk_tally` if one is open."""
+    with _LOCK:
+        _CHUNK_EVAL[path] += 1
+    tally = _CHUNK_TALLY.get()
+    if tally is not None:
+        tally[path] += 1
+
+
+@contextlib.contextmanager
+def chunk_tally(tally: dict):
+    """Count into ``tally`` (``{"sliced": n, "scan": n}``) the stages
+    traced while the block runs (in this thread or task only)."""
+    token = _CHUNK_TALLY.set(tally)
+    try:
+        yield
+    finally:
+        _CHUNK_TALLY.reset(token)
+
+
 def stats() -> dict:
     """Process-wide totals: ``pass_seconds`` (pass name -> wall seconds
     of every finished :class:`PassClock`), ``executor_runs`` and
     ``executor_seconds`` (entries into generated programs' executors
-    through ``Compiled.run``, and their wall seconds)."""
+    through ``Compiled.run``, and their wall seconds), and
+    ``chunk_eval_sliced`` / ``chunk_eval_scan`` (rank-2 stages traced on
+    the sliced chunk evaluator / on the chunk scan)."""
     with _LOCK:
         return {"pass_seconds": dict(_PASS_SECONDS),
                 "executor_runs": _EXECUTOR["runs"],
-                "executor_seconds": _EXECUTOR["seconds"]}
+                "executor_seconds": _EXECUTOR["seconds"],
+                "chunk_eval_sliced": _CHUNK_EVAL["sliced"],
+                "chunk_eval_scan": _CHUNK_EVAL["scan"]}

@@ -43,7 +43,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.compat import shard_map
 from repro.core import nest as nest_mod
-from repro.core import pragma, reduction as red_mod
+from repro.core import pragma, reduction as red_mod, tile_eval, timing
 from repro.core.context import ReadKind, VarClass, WriteKind
 from repro.core.loop import LoopNotCanonical, analyze_loop
 from repro.core.nest import LoopNest, ShiftedWindow, SubstitutionFailed  # noqa: F401 (re-export)
@@ -643,8 +643,36 @@ def _make_env_sub2(plan, env_in, slab_stacks, q_pair, k0s):
 
 def _run_local_chunks2(plan, program, env_in, slab_stacks, device_indices,
                        unroll_chunks=False):
-    """Scan this device's (chunk_i, chunk_j) pairs; returns
-    ``(carry, ys)`` with ys values laid out ``(n_i, c_i, n_j, c_j, *rest)``."""
+    """Run this device's (chunk_i, chunk_j) pairs; returns ``(carry, ys)``
+    with ys values laid out ``(n_i, c_i, n_j, c_j, *rest)``.
+
+    The body is evaluated once over the whole local chunk stack, every
+    window read served as a slice (:func:`tile_eval.eval_local_chunks2`);
+    a body whose window reads the evaluator cannot serve runs as a scan
+    of vmapped chunks instead.  Each path taken is counted
+    (``chunk_eval_sliced`` / ``chunk_eval_scan`` in ``omp.timing_stats``).
+    """
+    def sliced(device_indices, slab_stacks, env_in):
+        values = tile_eval.eval_local_chunks2(plan, program, env_in,
+                                              slab_stacks, device_indices)
+        return tile_eval.merge_chunk_values2(plan, values, device_indices)
+
+    try:
+        # one jit: a bare (eager) call compiles the stage once, not once
+        # per primitive of the evaluated body
+        out = jax.jit(sliced)(tuple(device_indices), slab_stacks, env_in)
+    except SubstitutionFailed:
+        timing.count_chunk_eval("scan")
+        return _scan_local_chunks2(plan, program, env_in, slab_stacks,
+                                   device_indices, unroll_chunks)
+    timing.count_chunk_eval("sliced")
+    return out
+
+
+def _scan_local_chunks2(plan, program, env_in, slab_stacks, device_indices,
+                        unroll_chunks=False):
+    """Scan this device's (chunk_i, chunk_j) pairs, the body vmapped over
+    each pair's lanes; the ``(carry, ys)`` of :func:`_run_local_chunks2`."""
     ch_i, ch_j = plan.chunks_axes
     loop_i, loop_j = plan.nest.axes
     d_i, d_j = device_indices
