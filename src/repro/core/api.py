@@ -1001,7 +1001,10 @@ class Compiled:
       executor from :meth:`run`, and their wall seconds,
     * ``.chunk_eval_sliced`` / ``.chunk_eval_scan`` — rank-2 stages
       those entries traced on the sliced chunk evaluator / on the
-      chunk scan.
+      chunk scan,
+    * ``.indirect_reads`` / ``.indirect_elems`` — stages those entries
+      traced with a read through an index array, and the index entries
+      they look up per call.
 
     The pipeline needs environment *shapes*; compile with ``env_like=``
     to run it eagerly, otherwise it runs (through the compilation
@@ -1027,6 +1030,12 @@ class Compiled:
     chunk_eval_scan: int = dataclasses.field(default=0, compare=False)
     """Rank-2 stages those entries ran as a scan of vmapped chunks (a
     window read the sliced evaluator cannot serve)."""
+    indirect_reads: int = dataclasses.field(default=0, compare=False)
+    """Stages those entries traced with a read through an index array
+    (``x[idx[i]]``, device scope ``omp.indirect.<keys>``)."""
+    indirect_elems: int = dataclasses.field(default=0, compare=False)
+    """Index entries those stages look up per call, summed over the
+    entries."""
     _exe: Any = dataclasses.field(default=None, repr=False)
     _passes: tuple | None = dataclasses.field(default=None, repr=False)
     _env_sig: tuple | None = dataclasses.field(default=None, repr=False)
@@ -1057,7 +1066,8 @@ class Compiled:
             if self._exe is None:
                 self._ensure(env, allow_restore=False)
             t0 = time.perf_counter()
-            tally = {"sliced": 0, "scan": 0}
+            tally = {"sliced": 0, "scan": 0, "indirect_reads": 0,
+                     "indirect_elems": 0}
             try:
                 with TraceAnnotation("omp.executor"), \
                         timing.chunk_tally(tally):
@@ -1068,6 +1078,8 @@ class Compiled:
                 self.executor_seconds += seconds
                 self.chunk_eval_sliced += tally["sliced"]
                 self.chunk_eval_scan += tally["scan"]
+                self.indirect_reads += tally["indirect_reads"]
+                self.indirect_elems += tally["indirect_elems"]
                 timing.add_executor(seconds)
         if _fault_hook is not None:
             out = _fault_hook("run_exit", out)

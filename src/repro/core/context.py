@@ -9,7 +9,9 @@ appears in each array access (the "linear first-dimension" rule of
 * reads are recovered from how each ``env`` buffer's invar is consumed —
   an invar whose every use is a ``dynamic_slice`` whose leading start
   index is an *affine* function of the iterator is a sliced read
-  (``x[a*i+b]``); any other use makes it a whole-array read;
+  (``x[a*i+b]``); a read whose indices come from a sliced read of an
+  integer buffer (``x[idx[i]]``) is an indirect read through that
+  buffer; any other use makes it a whole-array read;
 * writes are the declared :class:`~repro.core.pragma.At`/``Put``/``Red``
   updates; ``At`` indices are checked for affinity by symbolic affine
   propagation through the jaxpr (add/sub/mul/neg/convert chains seeded at
@@ -23,6 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import math
 from typing import Any, Mapping
 
 import jax
@@ -175,6 +178,7 @@ class ReadKind(enum.Enum):
     SLICED = "sliced"    # every use is x[a*i+b] on the leading dim
     STENCIL = "stencil"  # several unit-stride maps x[i+b0..i+bk] (halo)
     WHOLE = "whole"
+    INDIRECT = "indirect"  # x[idx[i]]: gathered through a sliced int read
 
 
 class WriteKind(enum.Enum):
@@ -201,6 +205,10 @@ class ReadInfo:
     # slices, and the distinct per-axis NestAffine index tuples
     slice_ndim: int = 0
     accesses: tuple | None = None
+    # INDIRECT reads: the index buffer, and the entries one iteration
+    # looks up through it
+    index_key: str | None = None
+    index_elems: int = 0
 
 
 @dataclasses.dataclass
@@ -240,6 +248,34 @@ class ContextInfo:
 # ---------------------------------------------------------------------------
 # Analysis driver
 # ---------------------------------------------------------------------------
+
+# Primitives an index vector passes through between its sliced read
+# (``idx[i]``) and the gather it feeds: jnp's negative-index wrap
+# (``lt``/``add``/``select_n``), reshapes and dtype converts.
+_INDEX_PRIMS = frozenset({
+    "squeeze", "reshape", "broadcast_in_dim", "expand_dims", "slice",
+    "convert_element_type", "copy", "lt", "add", "sub", "select_n",
+    "max", "min", "clamp"})
+
+
+def _source(sources, atom) -> str | None:
+    return None if isinstance(atom, jcore.Literal) else sources.get(atom)
+
+
+def _index_source(eqn, sources, aff) -> str | None:
+    """The index buffer that every non-constant operand of an
+    index-arithmetic ``eqn`` was read from, or ``None``."""
+    if eqn.primitive.name not in _INDEX_PRIMS or len(eqn.outvars) != 1:
+        return None
+    keys = set()
+    for iv in eqn.invars:
+        if isinstance(iv, jcore.Literal):
+            continue
+        if iv in sources:
+            keys.add(sources[iv])
+        elif (a := aff.lookup(iv)) is None or not a.is_const:
+            return None
+    return keys.pop() if len(keys) == 1 else None
 
 
 def _aval_of(x: Any) -> jax.ShapeDtypeStruct:
@@ -289,6 +325,12 @@ def analyze_context(program: pragma.ParallelFor, env: Mapping[str, Any],
     # plus a flag for non-slice uses.
     sliced_uses: dict[str, list[Affine | None]] = {k: [] for k in env_keys}
     whole_use: dict[str, bool] = {k: False for k in env_keys}
+    # indirect reads: key -> [(index key, entries looked up)]; sources
+    # maps each value computed from a sliced read of an integer buffer
+    # (and constants) to that buffer's key
+    indirect_uses: dict[str, list[tuple[str, int]]] = {
+        k: [] for k in env_keys}
+    sources: dict[Any, str] = {}
 
     for eqn in jaxpr.eqns:
         prim = eqn.primitive.name
@@ -305,18 +347,26 @@ def analyze_context(program: pragma.ParallelFor, env: Mapping[str, Any],
                     (a := aff.lookup(at)) is not None and a.is_const
                     for at in idx_atoms[1:]
                 )
-                if (
-                    lead is not None
-                    and sizes
-                    and sizes[0] == 1
-                    and rest_ok
-                    and len(shape) == len(sizes)
-                ):
+                shape_ok = sizes and sizes[0] == 1 and len(shape) == len(sizes)
+                via = _source(sources, idx_atoms[0]) if idx_atoms else None
+                if lead is not None and shape_ok and rest_ok:
                     sliced_uses[key].append(lead)
+                    if jnp.issubdtype(env_avals[key].dtype, jnp.integer):
+                        sources[eqn.outvars[0]] = key
+                elif via is not None and via != key and shape_ok and rest_ok:
+                    indirect_uses[key].append((via, 1))
                 else:
                     whole_use[key] = True
+            elif (prim == "gather" and pos == 0
+                    and _source(sources, eqn.invars[1]) not in (None, key)):
+                # one index tuple per entry of all but the index-vector axis
+                n = math.prod(eqn.invars[1].aval.shape[:-1])
+                indirect_uses[key].append((sources[eqn.invars[1]], n))
             else:
                 whole_use[key] = True
+        via = _index_source(eqn, sources, aff)
+        if via is not None:
+            sources[eqn.outvars[0]] = via
         aff.process(eqn)
 
     # --- write classification from the returned update structure -----------
@@ -386,8 +436,13 @@ def analyze_context(program: pragma.ParallelFor, env: Mapping[str, Any],
             # Reduction outputs may be fresh (not pre-existing in env).
             w = writes[key]
             shape, dtype = w.value_shape, w.value_dtype
-        if key in env_avals and whole_use[key]:
+        via = {k for k, _ in indirect_uses.get(key, ())}
+        if key in env_avals and (whole_use[key] or len(via) > 1):
             read = ReadInfo(ReadKind.WHOLE)
+        elif via:
+            # replicated like a whole read; its sliced uses read that copy
+            read = ReadInfo(ReadKind.INDIRECT, index_key=via.pop(),
+                            index_elems=sum(n for _, n in indirect_uses[key]))
         elif key in env_avals and sliced_uses[key]:
             affs = sliced_uses[key]
             if any(a is None for a in affs):

@@ -143,6 +143,13 @@ class DistPlan:
     def replicated_in_keys(self) -> list[str]:
         return [k for k, v in self.vars.items() if v.in_strategy == "replicate"]
 
+    @property
+    def indirect_reads(self) -> dict[str, str]:
+        """Keys read through an index array (replicated, gathered where
+        they are read), each with the index array's key."""
+        return {k: v.read.index_key for k, v in self.context.vars.items()
+                if v.read.kind == ReadKind.INDIRECT}
+
 
 @timed_pass("analyze")
 def analyze_program(
@@ -346,7 +353,7 @@ def decide_strategies(
         # master->worker full-buffer send).
         in_strategy = "none"
         halo = None
-        if info.read.kind == ReadKind.WHOLE:
+        if info.read.kind in (ReadKind.WHOLE, ReadKind.INDIRECT):
             in_strategy = "replicate"
         elif info.read.kind == ReadKind.SLICED:
             in_strategy = "replicate"
@@ -486,7 +493,7 @@ def _decide_strategies2(
         read_maps = None
         halo_axes = None
         shard_ndim = 0
-        if info.read.kind == ReadKind.WHOLE:
+        if info.read.kind in (ReadKind.WHOLE, ReadKind.INDIRECT):
             in_strategy = "replicate"
         elif info.read.kind in (ReadKind.SLICED, ReadKind.STENCIL):
             in_strategy = "replicate"

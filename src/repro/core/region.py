@@ -738,7 +738,8 @@ def _execute_region(dr: DistributedRegion, env: dict) -> dict:
                 elif dec.in_strategy == "replicate":
                     env_in[key] = st[key][1]
 
-            with jax.named_scope(f"omp.stage.{se.name}"):
+            with jax.named_scope(f"omp.stage.{se.name}"), \
+                    tf.indirect_scope(plan):
                 if not dr.use_pallas:
                     carry, ys = tf._run_local_chunks(
                         plan, se.stage, env_in, slab_stacks, d,

@@ -8,7 +8,7 @@ renders that, in a layout that mirrors the paper's tables.
 """
 from __future__ import annotations
 
-from repro.core.context import VarClass
+from repro.core.context import ReadKind, VarClass
 from repro.core.plan import DistPlan
 
 
@@ -80,6 +80,9 @@ def render_plan(plan: DistPlan) -> str:
         if dec.read_map is not None:
             lines.append(f"  {'':>12s}  read map : x[{dec.read_map.a}*k"
                          f"{dec.read_map.b:+d}]")
+        if info.read.kind == ReadKind.INDIRECT:
+            lines.append(f"  {'':>12s}  read     : indirect via "
+                         f"{info.read.index_key}")
         if dec.write_map is not None:
             lines.append(f"  {'':>12s}  write map: x[{dec.write_map.a}*k"
                          f"{dec.write_map.b:+d}]")
@@ -178,9 +181,11 @@ def render_region(rp) -> str:
                 f"({chs[0].num_chunks}x{chs[1].num_chunks} tiles cyclic)")
         else:
             ch = s.plan.chunks
+            via = "".join(f"; {k} indirect via {idx}"
+                          for k, idx in s.plan.indirect_reads.items())
             lines.append(
                 f"  {s.name:>16s}  loop t={s.plan.loop.trip_count} "
-                f"chunk={ch.chunk} ({ch.num_chunks} chunks cyclic)")
+                f"chunk={ch.chunk} ({ch.num_chunks} chunks cyclic){via}")
     lines.append("")
     lines.append("inter-loop residency (the beyond-paper layout planner):")
     if rp.log:

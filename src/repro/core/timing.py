@@ -15,9 +15,10 @@ passes.
 
 :func:`stats` gives the process-wide totals: pass seconds of every
 finished clock, the entries into the generated program's executor
-that ``Compiled.run`` records (:func:`add_executor`), and how many
+that ``Compiled.run`` records (:func:`add_executor`), how many
 rank-2 stages the executor traced on the sliced chunk evaluator or on the
-chunk scan (:func:`count_chunk_eval`).
+chunk scan (:func:`count_chunk_eval`), and how many stages it traced
+with a read through an index array (:func:`count_indirect`).
 """
 from __future__ import annotations
 
@@ -34,6 +35,7 @@ _LOCK = threading.Lock()
 _PASS_SECONDS: dict[str, float] = {}
 _EXECUTOR = {"runs": 0, "seconds": 0.0}
 _CHUNK_EVAL = {"sliced": 0, "scan": 0}
+_INDIRECT = {"reads": 0, "elems": 0}
 _CHUNK_TALLY: contextvars.ContextVar = contextvars.ContextVar(
     "omp_chunk_tally", default=None)
 
@@ -112,10 +114,24 @@ def count_chunk_eval(path: str) -> None:
         tally[path] += 1
 
 
+def count_indirect(elems: int) -> None:
+    """Count one stage traced with a read through an index array, whose
+    iteration space looks up ``elems`` index entries per call, in the
+    process totals and in the innermost :func:`chunk_tally`."""
+    with _LOCK:
+        _INDIRECT["reads"] += 1
+        _INDIRECT["elems"] += elems
+    tally = _CHUNK_TALLY.get()
+    if tally is not None:
+        tally["indirect_reads"] += 1
+        tally["indirect_elems"] += elems
+
+
 @contextlib.contextmanager
 def chunk_tally(tally: dict):
-    """Count into ``tally`` (``{"sliced": n, "scan": n}``) the stages
-    traced while the block runs (in this thread or task only)."""
+    """Count into ``tally`` (``{"sliced": n, "scan": n, "indirect_reads":
+    n, "indirect_elems": n}``) the stages traced while the block runs
+    (in this thread or task only)."""
     token = _CHUNK_TALLY.set(tally)
     try:
         yield
@@ -129,10 +145,14 @@ def stats() -> dict:
     ``executor_seconds`` (entries into generated programs' executors
     through ``Compiled.run``, and their wall seconds), and
     ``chunk_eval_sliced`` / ``chunk_eval_scan`` (rank-2 stages traced on
-    the sliced chunk evaluator / on the chunk scan)."""
+    the sliced chunk evaluator / on the chunk scan), ``indirect_reads``
+    and ``indirect_elems`` (stages traced with a read through an index
+    array, and the index entries they look up per call)."""
     with _LOCK:
         return {"pass_seconds": dict(_PASS_SECONDS),
                 "executor_runs": _EXECUTOR["runs"],
                 "executor_seconds": _EXECUTOR["seconds"],
                 "chunk_eval_sliced": _CHUNK_EVAL["sliced"],
-                "chunk_eval_scan": _CHUNK_EVAL["scan"]}
+                "chunk_eval_scan": _CHUNK_EVAL["scan"],
+                "indirect_reads": _INDIRECT["reads"],
+                "indirect_elems": _INDIRECT["elems"]}

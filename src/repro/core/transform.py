@@ -32,6 +32,7 @@ remains as a deprecation shim over it.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Mapping
@@ -404,6 +405,19 @@ def _slot_table(ch):
         ch.local_chunks, ch.num_devices))
 
 
+def indirect_scope(plan):
+    """The device scope ``omp.indirect.<keys>`` around the chunk compute
+    of a stage that reads through an index array (nested in its
+    ``omp.stage.<name>``), counted as one indirect stage; an empty
+    context for any other stage."""
+    keys = plan.indirect_reads
+    if not keys:
+        return contextlib.nullcontext()
+    timing.count_indirect(plan.nest.total_trip * sum(
+        plan.context.vars[k].read.index_elems for k in keys))
+    return jax.named_scope("omp.indirect." + "+".join(sorted(keys)))
+
+
 def _run_local_chunks(plan, program, env_in, slab_stacks, worker_index,
                       unroll_chunks=False):
     """Scan this device's chunks; returns (carry, ys_stacked)."""
@@ -475,7 +489,8 @@ def _execute_collective(dp: DistributedProgram, env: dict) -> dict:
         d = jax.lax.axis_index(axis)
         with jax.named_scope("omp.entry"):
             slab_stacks = {k: v[:, 0] for k, v in env_slab.items()}
-        with jax.named_scope(f"omp.stage.{program.name}"):
+        with jax.named_scope(f"omp.stage.{program.name}"), \
+                indirect_scope(plan):
             if dp.use_pallas:
                 carry, ys = plx.run_local_chunks_pallas(
                     plan, program, env_repl, slab_stacks, d,

@@ -1,4 +1,5 @@
-"""Compiles for a described TPU v5e, at PolyBench EXTRALARGE widths.
+"""Compiles for a described TPU v5e, at PolyBench EXTRALARGE widths and
+HPCG's benchmark grid.
 
 Nothing here runs: each test lowers and compiles the program the chip
 would run, for a v5e chip that is described and not attached (the TPU
@@ -10,6 +11,7 @@ The topology is described only inside a fixture of this module: only
 one process at a time may load the TPU library.
 """
 import os
+import sys
 
 import numpy as np
 import pytest
@@ -141,6 +143,35 @@ def test_pallas_span_compiles(topo, family):
     co = _compile(make(), _mesh(topo, shape, axes), shapes,
                   lowering="pallas")
     assert "tpu_custom_call" in co.as_text()
+
+
+def _bench_cell(name):
+    """A benchmark cell (``bench/``, beside ``src/`` in the checkout)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import harness
+    return harness.load_cell(name)
+
+
+def test_hpcg_cg_region_compiles_at_192(topo):
+    """The ``hpcg-192`` region (one CG iteration on a 192^3 grid, its
+    SpMV gathering ``p`` through ``cols``) fits one chip's 16 GB, its
+    row sums under ``omp.indirect.p`` inside ``omp.stage.spmv``."""
+    cell = _bench_cell("hpcg-192.stepped")
+    mesh = _mesh(topo, (1,), ("data",))
+    rep = NamedSharding(mesh, PartitionSpec())
+    like = jax.eval_shape(lambda k: cell.program.make_inputs(cell.cfg, k),
+                          jax.random.key(0))
+    like = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=rep)
+            for k, v in like.items()}
+    c = omp.compile(cell.program.build(cell.cfg), mesh, env_like=like)
+    co = jax.jit(lambda e: c(e)).lower(like).compile()
+    assert "omp.stage.spmv/omp.indirect.p/" in co.as_text()
+    mem = co.memory_analysis()
+    assert mem.argument_size_in_bytes >= 2 * 27 * 192 ** 3 * 4
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16e9
 
 
 def test_pallas_span_mosaic_refuses_raises_compile_error(topo):
