@@ -999,7 +999,7 @@ class Compiled:
     * ``.cache_hit``    — whether the last build came from the cache,
     * ``.executor_runs`` / ``.executor_seconds`` — entries into the
       executor from :meth:`run`, and their wall seconds,
-    * ``.chunk_eval_sliced`` / ``.chunk_eval_scan`` — rank-2 stages
+    * ``.chunk_eval_sliced`` / ``.chunk_eval_scan`` — lax stages
       those entries traced on the sliced chunk evaluator / on the
       chunk scan,
     * ``.indirect_reads`` / ``.indirect_elems`` — stages those entries
@@ -1025,10 +1025,10 @@ class Compiled:
     executor_seconds: float = dataclasses.field(default=0.0, compare=False)
     """Wall seconds of those entries (host span ``omp.executor``)."""
     chunk_eval_sliced: int = dataclasses.field(default=0, compare=False)
-    """Rank-2 stages those entries evaluated over the whole local chunk
-    stack, window reads served as slices."""
+    """Lax stages (rank 1 or 2) those entries evaluated over the whole
+    local chunk stack, window reads served as slices."""
     chunk_eval_scan: int = dataclasses.field(default=0, compare=False)
-    """Rank-2 stages those entries ran as a scan of vmapped chunks (a
+    """Lax stages those entries ran as a scan of vmapped chunks (a
     window read the sliced evaluator cannot serve)."""
     indirect_reads: int = dataclasses.field(default=0, compare=False)
     """Stages those entries traced with a read through an index array

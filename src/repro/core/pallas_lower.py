@@ -3,8 +3,8 @@
 ``Lowering.PALLAS`` keeps the whole distributed machinery of the
 collective/fused lowerings — chunk-cyclic staging, halo exchanges, the
 aggregated comm schedule, the jit-level reassembly — and swaps ONLY the
-per-device chunk compute: instead of a ``lax.scan`` of vmapped body
-chunks (:func:`repro.core.transform._run_local_chunks`), each compute
+per-device chunk compute: instead of the lax chunk compute
+(:func:`repro.core.transform._run_local_chunks`), each compute
 span runs as one tiled :func:`pl.pallas_call` over this device's local
 slab.  A *span* is a single loop stage, or — inside a fused region —
 the maximal chain of consecutive loop stages between scheduled
@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from repro.core import nest as nest_mod
 from repro.core.nest import AxisTiles, derive_axis_tiles
 from repro.core.tile_eval import (Src, eval_body, full, merge_chunk_values,
-                                  merge_chunk_values2, trace_body)
+                                  trace_body, window_origin)
 from repro.core.timing import timed_pass
 
 from jax.experimental import pallas as pl
@@ -267,14 +267,6 @@ class SpanStage:
     ext_windows: dict          # key -> local slab stacks (kernel input)
     env_repl: dict             # key -> replicated array (kernel input)
     forwarded: frozenset       # keys served from in-span producer tiles
-
-
-def _halo_base(dec, axis: int = 0) -> int:
-    if dec.in_strategy != "shard_halo":
-        return 0
-    if getattr(dec, "halo_axes", None) is not None:
-        return dec.halo_axes[axis][0]
-    return dec.halo[0] if dec.halo is not None else 0
 
 
 # VMEM per TensorCore, by device kind (the figures of
@@ -508,7 +500,7 @@ def execute_span(stages: list[SpanStage], device_indices: tuple,
                     srcs[key] = Src(
                         "win", span_vals[key] if key in sp.forwarded
                         else loaded[(si, key)],
-                        tuple(_halo_base(dec, a) for a in range(r)))
+                        tuple(window_origin(dec, a) for a in range(r)))
                 elif dec.in_strategy == "replicate":
                     srcs[key] = Src("val", loaded[(si, key)])
                 else:
@@ -557,30 +549,14 @@ def execute_span(stages: list[SpanStage], device_indices: tuple,
             else:
                 vals[key] = jnp.moveaxis(v, 1, 2)[
                     :, :tiles[0].chunk, :, :tiles[1].chunk]
-        if rank == 1:
-            results.append(merge_chunk_values(sp.plan, vals,
-                                              device_indices[0]))
-        else:
-            results.append(merge_chunk_values2(sp.plan, vals,
-                                               device_indices))
+        results.append(merge_chunk_values(sp.plan, vals, device_indices))
     return results
 
 
 def run_local_chunks_pallas(plan, program, env_in, slab_stacks,
-                            device_index, *, interpret: bool, device=None):
+                            device_indices, *, interpret: bool, device=None):
     """Drop-in for ``transform._run_local_chunks`` backed by one
     pallas_call over this device's slab."""
-    sp = SpanStage(name=plan.name, plan=plan, program=program,
-                   ext_windows=slab_stacks, env_repl=env_in,
-                   forwarded=frozenset())
-    (carry, ys), = execute_span([sp], (device_index,), interpret, device)
-    return carry, ys
-
-
-def run_local_chunks_pallas2(plan, program, env_in, slab_stacks,
-                             device_indices, *, interpret: bool,
-                             device=None):
-    """Rank-2 drop-in for ``transform._run_local_chunks2``."""
     sp = SpanStage(name=plan.name, plan=plan, program=program,
                    ext_windows=slab_stacks, env_repl=env_in,
                    forwarded=frozenset())

@@ -742,7 +742,7 @@ def _execute_region(dr: DistributedRegion, env: dict) -> dict:
                     tf.indirect_scope(plan):
                 if not dr.use_pallas:
                     carry, ys = tf._run_local_chunks(
-                        plan, se.stage, env_in, slab_stacks, d,
+                        plan, se.stage, env_in, slab_stacks, (d,),
                         dr.unroll_chunks)
                 else:
                     if si not in span_results:
@@ -1049,7 +1049,7 @@ def _execute_region2(dr: DistributedRegion, env: dict) -> dict:
 
             with jax.named_scope(f"omp.stage.{se.name}"):
                 if not dr.use_pallas:
-                    carry, ys = tf._run_local_chunks2(
+                    carry, ys = tf._run_local_chunks(
                         plan, se.stage, env_in, slab_stacks, (d_i, d_j),
                         dr.unroll_chunks)
                 else:

@@ -16,7 +16,7 @@ passes.
 :func:`stats` gives the process-wide totals: pass seconds of every
 finished clock, the entries into the generated program's executor
 that ``Compiled.run`` records (:func:`add_executor`), how many
-rank-2 stages the executor traced on the sliced chunk evaluator or on the
+lax stages the executor traced on the sliced chunk evaluator or on the
 chunk scan (:func:`count_chunk_eval`), and how many stages it traced
 with a read through an index array (:func:`count_indirect`).
 """
@@ -104,7 +104,7 @@ def add_executor(seconds: float) -> None:
 
 
 def count_chunk_eval(path: str) -> None:
-    """Count one rank-2 stage traced on the ``"sliced"`` chunk evaluator
+    """Count one lax stage traced on the ``"sliced"`` chunk evaluator
     or the ``"scan"`` of vmapped chunks, in the process totals and in
     the innermost :func:`chunk_tally` if one is open."""
     with _LOCK:
@@ -144,7 +144,7 @@ def stats() -> dict:
     of every finished :class:`PassClock`), ``executor_runs`` and
     ``executor_seconds`` (entries into generated programs' executors
     through ``Compiled.run``, and their wall seconds), and
-    ``chunk_eval_sliced`` / ``chunk_eval_scan`` (rank-2 stages traced on
+    ``chunk_eval_sliced`` / ``chunk_eval_scan`` (lax stages traced on
     the sliced chunk evaluator / on the chunk scan), ``indirect_reads``
     and ``indirect_elems`` (stages traced with a read through an index
     array, and the index entries they look up per call)."""

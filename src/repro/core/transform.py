@@ -418,9 +418,43 @@ def indirect_scope(plan):
     return jax.named_scope("omp.indirect." + "+".join(sorted(keys)))
 
 
-def _run_local_chunks(plan, program, env_in, slab_stacks, worker_index,
+def _run_local_chunks(plan, program, env_in, slab_stacks, device_indices,
                       unroll_chunks=False):
-    """Scan this device's chunks; returns (carry, ys_stacked)."""
+    """Run this device's local chunks of a stage (rank 1 or 2); returns
+    ``(carry, ys)`` with ys values laid out ``(n_0, c_0[, n_1, c_1],
+    *rest)``.
+
+    The body is evaluated once over the whole local chunk stack, every
+    window read and every unit-stride read of a replicated buffer served
+    as a slice (:func:`tile_eval.eval_local_chunks`); a body whose
+    window reads the evaluator cannot serve runs as a scan of vmapped
+    chunks instead.  Each path taken is counted (``chunk_eval_sliced``
+    / ``chunk_eval_scan`` in ``omp.timing_stats``).
+    """
+    def sliced(device_indices, slab_stacks, env_in):
+        values = tile_eval.eval_local_chunks(plan, program, env_in,
+                                             slab_stacks, device_indices)
+        return tile_eval.merge_chunk_values(plan, values, device_indices)
+
+    try:
+        # one jit: a bare (eager) call compiles the stage once, not once
+        # per primitive of the evaluated body
+        out = jax.jit(sliced)(tuple(device_indices), slab_stacks, env_in)
+    except SubstitutionFailed:
+        timing.count_chunk_eval("scan")
+        if plan.rank == 2:
+            return _scan_local_chunks2(plan, program, env_in, slab_stacks,
+                                       device_indices, unroll_chunks)
+        return _scan_local_chunks(plan, program, env_in, slab_stacks,
+                                  device_indices[0], unroll_chunks)
+    timing.count_chunk_eval("sliced")
+    return out
+
+
+def _scan_local_chunks(plan, program, env_in, slab_stacks, worker_index,
+                       unroll_chunks=False):
+    """Scan this device's chunks, the body vmapped over each chunk's
+    lanes; returns (carry, ys_stacked)."""
     ch = plan.chunks
     shapes = {k: plan.context.vars[k].shape for k in plan.vars}
     carry0 = _init_carry(plan)
@@ -493,11 +527,11 @@ def _execute_collective(dp: DistributedProgram, env: dict) -> dict:
                 indirect_scope(plan):
             if dp.use_pallas:
                 carry, ys = plx.run_local_chunks_pallas(
-                    plan, program, env_repl, slab_stacks, d,
+                    plan, program, env_repl, slab_stacks, (d,),
                     interpret=pallas_interp, device=mesh.devices.flat[0])
             else:
                 carry, ys = _run_local_chunks(plan, program, env_repl,
-                                              slab_stacks, d,
+                                              slab_stacks, (d,),
                                               dp.unroll_chunks)
 
         # With the aggregate schedule, every psum-family combine of the
@@ -656,38 +690,10 @@ def _make_env_sub2(plan, env_in, slab_stacks, q_pair, k0s):
     return env_sub
 
 
-def _run_local_chunks2(plan, program, env_in, slab_stacks, device_indices,
-                       unroll_chunks=False):
-    """Run this device's (chunk_i, chunk_j) pairs; returns ``(carry, ys)``
-    with ys values laid out ``(n_i, c_i, n_j, c_j, *rest)``.
-
-    The body is evaluated once over the whole local chunk stack, every
-    window read served as a slice (:func:`tile_eval.eval_local_chunks2`);
-    a body whose window reads the evaluator cannot serve runs as a scan
-    of vmapped chunks instead.  Each path taken is counted
-    (``chunk_eval_sliced`` / ``chunk_eval_scan`` in ``omp.timing_stats``).
-    """
-    def sliced(device_indices, slab_stacks, env_in):
-        values = tile_eval.eval_local_chunks2(plan, program, env_in,
-                                              slab_stacks, device_indices)
-        return tile_eval.merge_chunk_values2(plan, values, device_indices)
-
-    try:
-        # one jit: a bare (eager) call compiles the stage once, not once
-        # per primitive of the evaluated body
-        out = jax.jit(sliced)(tuple(device_indices), slab_stacks, env_in)
-    except SubstitutionFailed:
-        timing.count_chunk_eval("scan")
-        return _scan_local_chunks2(plan, program, env_in, slab_stacks,
-                                   device_indices, unroll_chunks)
-    timing.count_chunk_eval("sliced")
-    return out
-
-
 def _scan_local_chunks2(plan, program, env_in, slab_stacks, device_indices,
                         unroll_chunks=False):
     """Scan this device's (chunk_i, chunk_j) pairs, the body vmapped over
-    each pair's lanes; the ``(carry, ys)`` of :func:`_run_local_chunks2`."""
+    each pair's lanes; the ``(carry, ys)`` of :func:`_run_local_chunks`."""
     ch_i, ch_j = plan.chunks_axes
     loop_i, loop_j = plan.nest.axes
     d_i, d_j = device_indices
@@ -789,13 +795,13 @@ def _execute_collective2(dp: DistributedProgram, env: dict) -> dict:
                     slab_stacks[k] = v[:, 0]           # (n_i, w_i, *rest)
         with jax.named_scope(f"omp.stage.{program.name}"):
             if dp.use_pallas:
-                carry, ys = plx.run_local_chunks_pallas2(
+                carry, ys = plx.run_local_chunks_pallas(
                     plan, program, env_repl, slab_stacks, (d_i, d_j),
                     interpret=pallas_interp, device=mesh.devices.flat[0])
             else:
-                carry, ys = _run_local_chunks2(plan, program, env_repl,
-                                               slab_stacks, (d_i, d_j),
-                                               dp.unroll_chunks)
+                carry, ys = _run_local_chunks(plan, program, env_repl,
+                                              slab_stacks, (d_i, d_j),
+                                              dp.unroll_chunks)
         outs: dict[str, Any] = {}
         reduce_items: dict[str, tuple] = {}
         for key, dec in plan.vars.items():
@@ -908,8 +914,8 @@ def _execute_master_worker(dp: DistributedProgram, env: dict) -> dict:
                     env_in[key] = jnp.zeros(info.shape, info.dtype)
 
         with jax.named_scope(f"omp.stage.{program.name}"):
-            carry, ys = _run_local_chunks(plan, program, env_in, slab_stacks,
-                                          wd, dp.unroll_chunks)
+            carry, ys = _scan_local_chunks(plan, program, env_in,
+                                           slab_stacks, wd, dp.unroll_chunks)
 
         outs: dict[str, Any] = {}
         for key, dec in plan.vars.items():

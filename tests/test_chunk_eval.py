@@ -1,15 +1,18 @@
-"""The lax lowering runs a rank-2 stage over its whole local chunk stack.
+"""The lax lowering runs a stage over its whole local chunk stack.
 
-``transform._run_local_chunks2`` evaluates the body once over every
-local ``(chunk_i, chunk_j)`` pair, serving each window read as a slice
-(``tile_eval.eval_local_chunks2``), and falls back to a scan of vmapped
-chunks where the evaluator refuses a read.  Each case here runs both
-paths (the scan forced by making the evaluator refuse) and requires the
-same outputs to float32 rounding, and counts which path each stage took.
+``transform._run_local_chunks`` evaluates the body once over every
+local chunk (rank 1) or ``(chunk_i, chunk_j)`` pair (rank 2), serving
+each window read, and each unit-stride read of a replicated buffer, as
+a slice (``tile_eval.eval_local_chunks``), and falls back to a scan of
+vmapped chunks where the evaluator refuses a read.  Each case here runs
+both paths (the scan forced by making the evaluator refuse) and
+requires the same outputs to float32 rounding, and counts which path
+each stage took.
 """
 import json
 import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +28,8 @@ EPS = float(np.finfo(np.float32).eps)
 
 def mesh(shape):
     n = int(np.prod(shape))
-    return Mesh(np.array(jax.devices()[:n]).reshape(shape), ("i", "j"))
+    axes = ("i", "j") if len(shape) == 2 else ("data",)
+    return Mesh(np.array(jax.devices()[:n]).reshape(shape), axes)
 
 
 def data(shape, seed):
@@ -99,7 +103,85 @@ def strided(n):
                   "y": jnp.zeros((n, n), jnp.float32)}
 
 
-# name -> (builder, mesh shape, compile options, rank-2 stages per trace)
+def replicated2(n, m):
+    """``a[i+1, j-1]`` of a grid that is also summed whole, so
+    replicated: served from rows of it cut per ``(chunk_i, chunk_j)``."""
+    @omp.parallel_for(start=(0, 1), stop=(n - 1, m), collapse=2,
+                      name="replicated2")
+    def body(i, j, env):
+        a = env["a"]
+        return {"y": omp.at((i, j), a[i + 1, j - 1] * jnp.sum(a))}
+
+    return body, {"a": data((n, m), 14), "y": jnp.zeros((n, m), jnp.float32)}
+
+
+def hpcg_region():
+    """The benchmark's HPCG CG iteration on an 8x6x4 grid: an SpMV
+    reading ``p`` through ``cols``, two dot products, vector updates and
+    serial glue."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.programs import hpcg
+
+    cfg = {"NX": 8, "NY": 6, "NZ": 4, "SET_ITERS": 50}
+    return hpcg.build(cfg), hpcg.make_inputs(cfg, jax.random.key(7))
+
+
+def gemm(ni, nk, nj):
+    """PolyBench gemm's row loop: ``A[i]`` and ``C[i]`` row reads."""
+    @omp.parallel_for(stop=ni, name="gemm")
+    def body(i, env):
+        return {"C": omp.at(i, 1.5 * (env["A"][i] @ env["B"])
+                            + 1.2 * env["C"][i])}
+
+    return body, {"A": data((ni, nk), 7), "B": data((nk, nj), 8),
+                  "C": data((ni, nj), 9)}
+
+
+def jacobi1d(n):
+    """Two ping-pong 3-point sweeps over ``n - 2`` points, so the last
+    chunk is padded."""
+    def sweep(src, dst, name):
+        @omp.parallel_for(start=1, stop=n - 1, name=name)
+        def body(i, env):
+            x = env[src]
+            return {dst: omp.at(i, (x[i - 1] + x[i] + x[i + 1]) / 3.0)}
+        return body
+
+    return (omp.region(sweep("a", "b", "s0"), sweep("b", "a", "s1"),
+                       name="jacobi1d"),
+            {"a": data((n,), 10), "b": data((n,), 11)})
+
+
+def shifted(n):
+    """``x[i+3]`` and ``x[i-1]`` of a buffer that is also summed whole,
+    so replicated: the evaluator serves both reads from rows cut out of
+    it, and ``x[i+3]`` reaches the buffer's last element, where a slice
+    that started unpadded would clamp."""
+    @omp.parallel_for(start=1, stop=n - 3, name="shifted")
+    def body(i, env):
+        x = env["x"]
+        return {"y": omp.at(i, x[i + 3] - x[i - 1] + jnp.sum(x))}
+
+    return body, {"x": data((n,), 12), "y": jnp.zeros(n, jnp.float32)}
+
+
+def scatter_put(n):
+    """A strided write (``scatter``), the last iteration's value
+    (``put``) and a reduction of one loop."""
+    @omp.parallel_for(stop=n, reduction={"s": "+"}, name="scatter_put")
+    def body(i, env):
+        x = env["x"][i]
+        return {"z": omp.at(3 * i + 2, x * 2.0),
+                "w": omp.put(jnp.full((4,), 1.0, jnp.float32) * x),
+                "s": omp.red(x)}
+
+    return body, {"x": data((n,), 13), "z": -jnp.ones(3 * n + 2, jnp.float32),
+                  "w": jnp.zeros(4, jnp.float32), "s": jnp.float32(0.5)}
+
+
+# name -> (builder, mesh shape, compile options, stages per trace)
 CASES = {
     "jacobi_1x1": (lambda: jacobi(24, 24, sweeps=4), (1, 1), {}, 4),
     "jacobi_2x2": (lambda: jacobi(40, 40), (2, 2), {}, 2),
@@ -107,6 +189,13 @@ CASES = {
     "reduce": (lambda: reduce_region(19, 23), (1, 1), {}, 2),
     "block": (lambda: block(21, 26), (1, 1), {"shard": "slice"}, 1),
     "strided_read": (lambda: strided(9), (1, 1), {}, 1),
+    "replicated_2d": (lambda: replicated2(13, 11), (1, 1), {}, 1),
+    "hpcg_1": (hpcg_region, (1,), {}, 3),
+    "hpcg_4": (hpcg_region, (4,), {}, 3),
+    "gemm_rows": (lambda: gemm(23, 17, 19), (1,), {}, 1),
+    "uneven_rank1": (lambda: jacobi1d(37), (1,), {}, 2),
+    "replicated_tail": (lambda: shifted(41), (1,), {}, 1),
+    "scatter_put": (lambda: scatter_put(23), (4,), {}, 1),
 }
 
 
@@ -123,13 +212,13 @@ def compare(name: str) -> dict:
     got = {}
     for path in ("sliced", "scan"):
         compiled = omp.compile(prog, m, **options)
-        saved = tile_eval.eval_local_chunks2
+        saved = tile_eval.eval_local_chunks
         if path == "scan":
-            tile_eval.eval_local_chunks2 = _refuse
+            tile_eval.eval_local_chunks = _refuse
         try:
             out = jax.jit(lambda e: compiled(e))(env)
         finally:
-            tile_eval.eval_local_chunks2 = saved
+            tile_eval.eval_local_chunks = saved
         got[path] = ({k: np.asarray(v) for k, v in out.items()},
                      (compiled.chunk_eval_sliced, compiled.chunk_eval_scan))
     (sliced, counts_sliced), (scan, counts_scan) = got["sliced"], got["scan"]
@@ -168,15 +257,15 @@ OP = re.compile(r"= (?:\([^()]*\)|\S+) ([a-z][\w-]*)\(")
 OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
-def stage_ops(text: str) -> set:
-    """Under an ``omp.stage.*`` scope of the optimized HLO: the opcodes,
-    and the JAX primitives the ops came from (a backend may wrap a loop
-    in a call)."""
+def stage_ops(text: str, scope: str = "omp.stage.") -> set:
+    """Under the scope ``scope`` (by default any ``omp.stage.*``) of the
+    optimized HLO: the opcodes, and the JAX primitives the ops came from
+    (a backend may wrap a loop in a call)."""
     ops = set()
     for line in text.splitlines():
-        op, scope = OP.search(line), OP_NAME.search(line)
-        if op and scope and "/omp.stage." in "/" + scope.group(1):
-            ops.update((op.group(1), scope.group(1).split("/")[-1]))
+        op, name = OP.search(line), OP_NAME.search(line)
+        if op and name and "/" + scope in "/" + name.group(1):
+            ops.update((op.group(1), name.group(1).split("/")[-1]))
     return ops
 
 
@@ -191,14 +280,39 @@ def test_sliced_stages_compile_to_no_gather_and_no_loop():
     # the scan it falls back to keeps its chunk loop
     before = omp.timing_stats()
     scan = omp.compile(reg, mesh((1, 1)))
-    saved, tile_eval.eval_local_chunks2 = (tile_eval.eval_local_chunks2,
+    saved, tile_eval.eval_local_chunks = (tile_eval.eval_local_chunks,
                                            _refuse)
     try:
         text = jax.jit(lambda e: scan(e)).lower(env).compile().as_text()
     finally:
-        tile_eval.eval_local_chunks2 = saved
+        tile_eval.eval_local_chunks = saved
     assert (scan.chunk_eval_sliced, scan.chunk_eval_scan) == (0, 4)
     assert "while" in stage_ops(text)
     after = omp.timing_stats()
     assert after["chunk_eval_scan"] - before["chunk_eval_scan"] == 4
     assert after["chunk_eval_sliced"] == before["chunk_eval_sliced"]
+
+
+GATHER = re.compile(r"= \w+\[([\d,]*)\]\S* gather\(.*slice_sizes=\{([\d,]*)\}")
+
+
+def test_rank1_sliced_stages_read_slices_and_gather_only_through_cols():
+    """HPCG's rank-1 stages: the vector updates compile to no gather and
+    no loop; the SpMV's only gathers are of ``p`` through ``cols`` (one
+    entry per stored slot), its rows of ``vals`` and ``cols`` and its
+    ``p[i]`` read as slices."""
+    prog, env = hpcg_region()
+    compiled = omp.compile(prog, mesh((1,)))
+    text = jax.jit(lambda e: compiled(e)).lower(env).compile().as_text()
+    assert (compiled.chunk_eval_sliced, compiled.chunk_eval_scan) == (3, 0)
+    for stage in ("update", "direction"):
+        ops = stage_ops(text, f"omp.stage.{stage}/")
+        assert ops and not ops & {"gather", "while"}, (stage, ops)
+    gathers = [GATHER.search(line).groups() for line in text.splitlines()
+               if " gather(" in line
+               and "omp.stage.spmv/omp.indirect.p/" in line]
+    # scalars of p, 27 per row (one per stored slot): no row of vals or
+    # cols (slices of 27) and no p[i] (one per row)
+    assert gathers and all(
+        sizes == "1" and np.prod([int(n) for n in shape.split(",")]) % 27
+        == 0 for shape, sizes in gathers), gathers

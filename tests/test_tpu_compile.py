@@ -172,6 +172,10 @@ def test_hpcg_cg_region_compiles_at_192(topo):
     assert mem.argument_size_in_bytes >= 2 * 27 * 192 ** 3 * 4
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < 16e9
+    # the scan of vmapped chunks needed 4,532,523,008 bytes of
+    # temporaries; evaluating stages over the local chunk stack may add
+    # at most 5% (a vmap over the stack would hold every chunk's gather)
+    assert mem.temp_size_in_bytes <= 4_759_149_158
 
 
 def test_pallas_span_mosaic_refuses_raises_compile_error(topo):
